@@ -31,6 +31,7 @@ import numpy as np
 
 from repro import obs
 from repro.dataset import SpatialDatasetScanner, write_dataset
+from repro.kernels import enable_compile_cache
 from repro.serve.query_scheduler import SpatialQueryServer
 
 from .common import make_dataset
@@ -116,6 +117,7 @@ def main() -> None:
     ap.add_argument("--out", default="BENCH_read.json",
                     help="merge results under the 'serve' key of this JSON")
     args = ap.parse_args()
+    enable_compile_cache()
     result = run(scale=args.scale, dataset=args.dataset, n_shards=args.shards,
                  query_counts=tuple(args.queries), device=args.device,
                  max_wave=args.max_wave)

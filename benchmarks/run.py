@@ -3,7 +3,9 @@
 ``PYTHONPATH=src python -m benchmarks.run [--scale S] [--only t2,t3,...]``
 
 Prints ``name,us_per_call,derived`` CSV rows (one per measurement) followed
-by per-table human summaries. Results also land in results/bench.json.
+by per-table human summaries. Results also land in results/bench.json. A
+module that fails prints an ``ERROR`` row; the rest still run, and the
+harness then exits non-zero.
 """
 
 from __future__ import annotations
@@ -43,6 +45,7 @@ def main() -> None:
     only = {s.strip() for s in args.only.split(",") if s.strip()}
 
     all_rows = {}
+    failed = []
     print("name,us_per_call,derived")
     for name, mod in modules.items():
         if only and name not in only:
@@ -54,6 +57,7 @@ def main() -> None:
             print(f"{name},0,ERROR:{type(e).__name__}:{e}", flush=True)
             import traceback
             traceback.print_exc(file=sys.stderr)
+            failed.append(name)
             continue
         dt = time.perf_counter() - t0
         all_rows[name] = rows
@@ -80,6 +84,8 @@ def main() -> None:
     with open("results/bench.json", "w") as fh:
         json.dump(all_rows, fh, indent=1, default=str)
     print("\n[bench] saved results/bench.json")
+    if failed:
+        sys.exit(f"[bench] failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -58,6 +58,7 @@ from repro.dataset import (
     write_dataset,
 )
 from repro.io import InProcessRangeServer, RemoteRangeSource
+from repro.kernels import enable_compile_cache
 
 from .common import SCALE_1, make_dataset, tmppath
 
@@ -306,6 +307,7 @@ def main() -> None:
                          "bit-identical to the untraced one, and write the "
                          "Chrome trace-event JSON here")
     args = ap.parse_args()
+    enable_compile_cache()
     result = run(scale=args.scale, dataset=args.dataset, repeats=args.repeats,
                  n_shards=args.shards, trace=args.trace)
     with open(args.out, "w") as fh:

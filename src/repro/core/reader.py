@@ -858,10 +858,10 @@ class SpatialParquetReader:
 
         Per row group: levels decode on the host (they drive segmentation),
         every hit coordinate page becomes a plan (raw pages via the synthetic
-        raw-mode plan) and joins one fused launch chain per VMEM-sized chunk
-        (`decode_refine_stream`). Only the per-record survivor mask and the
-        surviving coordinate values cross back to the host — or nothing at
-        all with ``keep_on_device=True``.
+        raw-mode plan) and joins one fused launch chain per chunk under the
+        launch cap (`decode_refine_stream`). Only the per-record survivor
+        mask and the surviving coordinate values cross back to the host — or
+        nothing at all with ``keep_on_device=True``.
 
         With ``filter`` the host-evaluated attribute mask is AND-ed into the
         chunk's per-record ``valid`` operand before the launch, so the device
@@ -960,13 +960,14 @@ class SpatialParquetReader:
                     attr_rg = filter.mask(
                         {k: extra_all[k][we0:we] for k in filter.columns()})
 
-                # chunk page pairs into VMEM-sized fused launches
+                # chunk page pairs into fused launches under the launch cap
                 for kind, cplans, cpairs, (rl, rh) in chunk_plan_pairs(plans, pairs):
                     vc = rec_vcounts[rl:rh]
                     attr_c = attr_rg[rl:rh] if attr_rg is not None else None
                     if kind == "host":
                         # a single page too large for any launch: decode this
-                        # pair on the host (same bits via fp_delta_execute)
+                        # pair on the host (same bits via fp_delta_execute;
+                        # counted in device.host_fallback_pages)
                         with obs.span("rg.launch", cat="decode", rg=rg_i,
                                       kind="host"):
                             x_v = fp_delta_execute(cplans[0])

@@ -30,7 +30,9 @@ pruning ratios are measured against the whole dataset.
 
 from __future__ import annotations
 
+import struct
 import time
+import zlib
 from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
@@ -49,6 +51,15 @@ from .manifest import DatasetManifest, shard_path
 
 ON_ERROR_POLICIES = ("raise", "retry", "skip")
 
+# What ``on_error`` governs: a shard whose bytes fail to arrive (I/O,
+# timeouts, checksums — all OSError) or fail to parse (format errors). A
+# device program that fails to compile or run (``jax.errors.
+# JaxRuntimeError``, ``repro.kernels.DeviceCompileError``) is no fault of
+# the shard; it propagates at once under every policy instead of turning
+# into a skipped shard and a short answer.
+SHARD_ERRORS = (OSError, ValueError, EOFError, KeyError, IndexError,
+                struct.error, zlib.error)
+
 
 class SpatialDatasetScanner:
     """Query interface over a sharded Spatial Parquet dataset.
@@ -63,6 +74,9 @@ class SpatialDatasetScanner:
     :class:`ShardFailure` in ``stats.failures`` — the scan returns every
     healthy shard's records, bit-identical to a clean scan minus the skipped
     shards.
+
+    Only :data:`SHARD_ERRORS` go to the policy; any other error — a device
+    program that fails to compile or run among them — propagates unchanged.
 
     ``source_factory``, if given, maps a shard's absolute path to a
     :class:`~repro.io.source.ByteRangeSource` — the hook that points a scan
@@ -212,7 +226,8 @@ class SpatialDatasetScanner:
         successful attempt folds its own deltas inside ``read_columnar``);
         raises only under ``on_error="raise"`` (immediately) or ``"retry"``
         (after exhausting ``shard_retries``), always as an attributed
-        :class:`ShardReadError`.
+        :class:`ShardReadError`. Errors outside :data:`SHARD_ERRORS`
+        propagate unchanged from the first attempt.
         """
         path = shard_path(self.root, manifest.shards[shard_i])
         retries = 0 if self.on_error == "raise" else self.shard_retries
@@ -225,7 +240,7 @@ class SpatialDatasetScanner:
                         path, bbox, columns, refine, coalesce, device,
                         keep_on_device, filter)
                     return res, attempt, None, failed
-                except Exception as exc:
+                except SHARD_ERRORS as exc:
                     last = exc
                     partial = getattr(exc, "spqf_source_stats", None)
                     if partial is not None:

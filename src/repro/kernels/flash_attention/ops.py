@@ -2,14 +2,10 @@
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from .. import default_interpret
 from . import kernel, ref
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def attention(
@@ -39,7 +35,7 @@ def attention(
         v = jnp.repeat(v, rep, axis=1)
     if not use_pallas:
         return ref.attention_ref(q, k, v, causal=causal, sm_scale=sm_scale)
-    interp = _default_interpret() if interpret is None else interpret
+    interp = default_interpret() if interpret is None else interpret
     # The kernel takes no mask input, so the key length must be block-aligned
     # (serving caches and training seq lens are). Queries are *front*-padded:
     # real query i lands on padded row i+pad, which preserves the causal

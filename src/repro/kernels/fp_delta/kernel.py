@@ -20,7 +20,8 @@ The module also hosts the *page-stream* decode kernel
 (:func:`decode_stream_blocks`): on-device execution of the paper-exact
 FP-delta page format from host-resolved ``FPDeltaPlan``s — see the
 "page stream" section of ref.py for the format math and ops.py for the
-batching layer that feeds it.
+batching layer that feeds it. It is the lake's device decode and compiles
+for the TPU (``tests/test_tpu_compile.py``).
 """
 
 from __future__ import annotations
@@ -30,7 +31,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from ..tile_scan import tile_scan
 from .ref import (
     MAX_EXC,
     MINIBLOCK,
@@ -38,11 +41,11 @@ from .ref import (
     WIDTHS,
     choose_width,
     extract_exceptions,
-    gather_tokens,
+    extract_tokens,
+    gather_words,
     inject_exceptions,
     pack_candidate,
     seg_combine,
-    segmented_scan,
     significant_bits_u32,
     stream_values,
     unpack_candidate,
@@ -134,26 +137,26 @@ def encode_blocks(x: jnp.ndarray, *, interpret: bool = True):
 
 # --------------------------------------------------------------- page stream
 # Decode kernel for the paper-exact FP-delta page format (see ref.py "page
-# stream" section for the math). Each grid step decodes one STREAM_BLOCK of
-# the concatenated value stream: fixed-width gather from the shared packed
-# words (whole array resident per step), escape injection, un-zigzag, and a
-# block-local segmented scan. Cross-block carries are stitched afterwards
-# with one tiny associative scan over per-block summaries — the grid stays
-# embarrassingly parallel, like the miniblock codec above.
+# stream" section for the math). The data-dependent part — fetching each
+# token's three-word window from the packed words — is one XLA gather ahead
+# of the kernel (Mosaic lowers no 1-D gather). Each grid step then decodes
+# one STREAM_BLOCK of the concatenated value stream as one (8, 128) tile:
+# token extraction, escape injection, un-zigzag, and a block-local segmented
+# scan. Cross-block carries are stitched afterwards with one tiny
+# associative scan over per-block summaries — the grid stays embarrassingly
+# parallel, like the miniblock codec above.
 
 
-def _stream_decode_kernel(words_ref, off_ref, nbits_ref, anch_ref,
-                          lo_ref, hi_ref, seen_ref):
-    words = words_ref[...].reshape(-1).astype(jnp.uint32)
-    offs = off_ref[...].reshape(STREAM_BLOCK)
-    nb = nbits_ref[...].reshape(STREAM_BLOCK)
-    anc = anch_ref[...].reshape(STREAM_BLOCK) != 0
-    lo, hi = gather_tokens(words, offs, nb)
+def _stream_decode_kernel(w0_ref, w1_ref, w2_ref, off_ref, nbits_ref,
+                          anch_ref, lo_ref, hi_ref, seen_ref):
+    anc = anch_ref[0] != 0
+    lo, hi = extract_tokens(w0_ref[0], w1_ref[0], w2_ref[0], off_ref[0],
+                            nbits_ref[0])
     vlo, vhi = stream_values(lo, hi, anc)
-    flo, fhi, seen = segmented_scan(vlo, vhi, anc)
-    lo_ref[...] = flo.astype(jnp.int32).reshape(1, *_BLOCK_2D)
-    hi_ref[...] = fhi.astype(jnp.int32).reshape(1, *_BLOCK_2D)
-    seen_ref[...] = seen.astype(jnp.int32).reshape(1, *_BLOCK_2D)
+    flo, fhi, seen = tile_scan(seg_combine, (vlo, vhi, anc), (0, 0, False))
+    lo_ref[0] = flo
+    hi_ref[0] = fhi
+    seen_ref[0] = seen.astype(jnp.int32)
 
 
 def decode_stream_limbs(words32, tok_off, nbits, anchor, *, interpret: bool = True):
@@ -166,33 +169,25 @@ def decode_stream_limbs(words32, tok_off, nbits, anchor, *, interpret: bool = Tr
     transform and segmented bbox reduction run directly on the limbs.
     """
     n_blocks = tok_off.shape[0]
-    wr = words32.reshape(-1, 128)
-    o2 = tok_off.reshape(n_blocks, *_BLOCK_2D)
-    n2 = nbits.reshape(n_blocks, *_BLOCK_2D)
-    a2 = anchor.reshape(n_blocks, *_BLOCK_2D)
+    tile = (n_blocks, *_BLOCK_2D)
+    windows = [w.reshape(tile) for w in gather_words(words32, tok_off)]
+    spec = pl.BlockSpec((1, *_BLOCK_2D), lambda b: (b, 0, 0))
     outs = pl.pallas_call(
         _stream_decode_kernel,
         grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec(wr.shape, lambda b: (0, 0)),  # whole words array
-            pl.BlockSpec((1, *_BLOCK_2D), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, *_BLOCK_2D), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, *_BLOCK_2D), lambda b: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, *_BLOCK_2D), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, *_BLOCK_2D), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, *_BLOCK_2D), lambda b: (b, 0, 0)),
-        ],
+        in_specs=[spec] * 6,
+        out_specs=[spec] * 3,
         out_shape=[
-            jax.ShapeDtypeStruct((n_blocks, *_BLOCK_2D), jnp.int32),
-            jax.ShapeDtypeStruct((n_blocks, *_BLOCK_2D), jnp.int32),
-            jax.ShapeDtypeStruct((n_blocks, *_BLOCK_2D), jnp.int32),
+            jax.ShapeDtypeStruct(tile, jnp.uint32),
+            jax.ShapeDtypeStruct(tile, jnp.uint32),
+            jax.ShapeDtypeStruct(tile, jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(wr, o2, n2, a2)
-    lo = outs[0].reshape(n_blocks, STREAM_BLOCK).astype(jnp.uint32)
-    hi = outs[1].reshape(n_blocks, STREAM_BLOCK).astype(jnp.uint32)
+    )(*windows, tok_off.reshape(tile), nbits.reshape(tile),
+      anchor.reshape(tile))
+    lo = outs[0].reshape(n_blocks, STREAM_BLOCK)
+    hi = outs[1].reshape(n_blocks, STREAM_BLOCK)
     seen = outs[2].reshape(n_blocks, STREAM_BLOCK) != 0
     # Carry stitch: block b inherits the running value of the last anchor
     # segment before it — an exclusive segmented combine of the per-block

@@ -34,14 +34,11 @@ from repro import obs
 from repro.core.columnar import DeviceCoords
 from repro.core.fp_delta import HEADER_BITS, FPDeltaPlan, fp_delta_execute
 
+from .. import DeviceCompileError, default_interpret
 from . import kernel, ref
 from .ref import EXC_BITS, MAX_EXC, MINIBLOCK, STREAM_BLOCK
 
 _MAGIC = b"FPD2"  # FP-Delta Miniblock v2 (patched)
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # --------------------------------------------------------- AOT compile cache
@@ -55,6 +52,7 @@ _COMPILED: dict[tuple, object] = {}
 def _aot(key: tuple, jitted, args: tuple, statics: dict | None = None):
     """Return the compiled executable for ``jitted`` at ``args``' shapes.
 
+    A failed compile raises :class:`~repro.kernels.DeviceCompileError`.
     Compile-vs-execute attribution: a cache miss traces+compiles inside a
     ``jit.compile`` span (cat ``jit``) and bumps the ``jit.compiles``
     counter; a hit bumps ``jit.cache_hits`` — so a trace separates one-time
@@ -69,7 +67,11 @@ def _aot(key: tuple, jitted, args: tuple, statics: dict | None = None):
                     shapes = tuple(
                         jax.ShapeDtypeStruct(np.shape(a), a.dtype) for a in args
                     )
-                    fn = jitted.lower(*shapes, **(statics or {})).compile()
+                    try:
+                        fn = jitted.lower(*shapes, **(statics or {})).compile()
+                    except Exception as exc:
+                        raise DeviceCompileError(
+                            f"compiling {key!r} failed: {exc}") from exc
                 obs.count("jit.compiles")
                 _COMPILED[key] = fn
                 return fn
@@ -122,7 +124,7 @@ def _pad_to_blocks(x) -> tuple[jnp.ndarray, int]:
 def encode(x, *, use_pallas: bool = True, interpret: bool | None = None) -> MiniblockStream:
     blocks, n = _pad_to_blocks(x)
     if use_pallas:
-        interp = _default_interpret() if interpret is None else interpret
+        interp = default_interpret() if interpret is None else interpret
         outs = kernel.encode_blocks(blocks, interpret=interp)
     else:
         outs = jax.jit(ref.encode_blocks_ref)(blocks)
@@ -134,7 +136,7 @@ def decode(stream: MiniblockStream, *, use_pallas: bool = True,
     args = (stream.packed, stream.widths, stream.anchors,
             stream.exc_idx, stream.exc_val, stream.exc_count)
     if use_pallas:
-        interp = _default_interpret() if interpret is None else interpret
+        interp = default_interpret() if interpret is None else interpret
         x = kernel.decode_blocks(*args, interpret=interp)
     else:
         x = jax.jit(ref.decode_blocks_ref)(*args)
@@ -208,11 +210,12 @@ def from_bytes(buf: bytes) -> MiniblockStream:
 # the anchor flags doubling as the segment-id boundaries of the device-side
 # segmented cumsum. One launch decodes a whole row group.
 
-# Per-launch cap on packed payload bits. Two constraints: token offsets are
-# int32 bit addresses (< 2^31), and the kernel stages the whole word buffer
-# into VMEM each grid step, so one launch's words must fit comfortably in
-# ~16 MiB of VMEM. 2^26 bits = 8 MiB of words; typical row groups are far
-# smaller and still decode in a single launch.
+# Per-launch cap on packed payload bits: 2^26 bits = 8 MiB of words in HBM.
+# Token offsets are int32 bit addresses (< 2^31), and the cap bounds the pow2
+# shape buckets a launch can compile to. The kernel itself streams (8, 128)
+# blocks, so VMEM does not grow with the launch. Typical row groups are far
+# smaller and decode in a single launch; a single page above the cap decodes
+# on the host and counts in ``device.host_fallback_pages``.
 _MAX_LAUNCH_BITS = 1 << 26
 
 
@@ -349,7 +352,7 @@ def decode_stream_device(stream: PageStream, *, use_pallas: bool = True,
     ``n_blocks * STREAM_BLOCK`` (tail is padding; ``hi`` is zero for 32-bit
     streams). The bit patterns equal the host decode exactly.
     """
-    interp = _default_interpret() if interpret is None else interpret
+    interp = default_interpret() if interpret is None else interpret
     args = _stream_args(stream)
     key = ("limbs", stream.words32.shape[0], stream.tok_off.shape[0],
            use_pallas, interp)
@@ -370,8 +373,9 @@ def decode_page_stream(stream: PageStream, *, use_pallas: bool = True,
         return np.zeros(0, dtype)
     lo, hi = decode_stream_device(
         stream, use_pallas=use_pallas, interpret=interpret)
-    return DeviceCoords(lo[:n], hi[:n] if stream.width == 64 else None,
-                        np.dtype(dtype)).to_numpy()
+    # trim on the host: a device slice compiles one program per length
+    return DeviceCoords(lo, hi if stream.width == 64 else None,
+                        np.dtype(dtype)).to_numpy()[:n]
 
 
 def _plan_bits(p: FPDeltaPlan) -> int:
@@ -384,10 +388,11 @@ def decode_pages(plans, *, use_pallas: bool = True,
                  interpret: bool | None = None) -> list[np.ndarray]:
     """Decode many host-resolved pages on-device; one array per plan.
 
-    Pages are greedily packed into as few VMEM-sized launches as possible
-    (one launch for a typical row group). A single page too large for any
-    launch falls back to the host ``fp_delta_execute`` — same bits either
-    way. Results are bit-identical to the host decode on every page.
+    Pages are greedily packed into as few launches under the cap as
+    possible (one launch for a typical row group). A single page too large
+    for any launch falls back to the host ``fp_delta_execute`` — same bits
+    either way — and counts in ``device.host_fallback_pages``. Results are
+    bit-identical to the host decode on every page.
     """
     plans = list(plans)
     out: list[np.ndarray] = []
@@ -408,6 +413,7 @@ def decode_pages(plans, *, use_pallas: bool = True,
         if pbits > _MAX_LAUNCH_BITS:  # one giant page: host-decode it
             flush(chunk)
             chunk, bits = [], 0
+            obs.count("device.host_fallback_pages")
             out.append(fp_delta_execute(p))
             continue
         if chunk and bits + pbits > _MAX_LAUNCH_BITS:
@@ -420,7 +426,7 @@ def decode_pages(plans, *, use_pallas: bool = True,
 
 
 def chunk_plan_pairs(plans, pairs):
-    """Group x/y page-pair plans into fused launches under the VMEM cap.
+    """Group x/y page-pair plans into fused launches under the launch cap.
 
     ``plans[2i]``/``plans[2i+1]`` are the x/y plans of pair ``i``;
     ``pairs[i] = (rec_lo, rec_hi)`` its record range. Yields ``("dev",
@@ -428,8 +434,9 @@ def chunk_plan_pairs(plans, pairs):
     ``("host", (plan_x, plan_y), None, (rec_lo, rec_hi))`` for a pair whose
     packed payload alone exceeds the cap (the caller host-decodes it via
     ``fp_delta_execute`` — records never straddle pages, so chunk masks
-    concatenate exactly). Lives next to :data:`_MAX_LAUNCH_BITS` so the cap
-    accounting has a single owner (shared with :func:`decode_pages`).
+    concatenate exactly; both pages count in ``device.host_fallback_pages``).
+    Lives next to :data:`_MAX_LAUNCH_BITS` so the cap accounting has a
+    single owner (shared with :func:`decode_pages`).
     """
     cur_plans: list = []
     cur_pairs: list = []
@@ -442,6 +449,7 @@ def chunk_plan_pairs(plans, pairs):
                 yield ("dev", cur_plans, cur_pairs,
                        (cur_pairs[0][0], cur_pairs[-1][1]))
                 cur_plans, cur_pairs, bits = [], [], 0
+            obs.count("device.host_fallback_pages", 2)
             yield ("host", (px, py), None, (r0, r1))
             continue
         if cur_plans and bits + pbits > _MAX_LAUNCH_BITS:
@@ -602,7 +610,7 @@ def decode_refine_stream(stream: PageStream, aux: RefineAux, bbox, *,
     """
     from repro.kernels.minmax import bbox_query_keys
 
-    interp = _default_interpret() if interpret is None else interpret
+    interp = default_interpret() if interpret is None else interpret
     dtype = np.float32 if stream.width == 32 else np.float64
     qkeys = bbox_query_keys(bbox, dtype)
     if qkeys is None:  # NaN bound: the host compare keeps nothing
@@ -729,7 +737,7 @@ def decode_refine_stream_multi(stream: PageStream, aux: RefineAux, qkeys,
     The query axis is pow2-padded so the compiled shape is shared across
     nearby wave sizes.
     """
-    interp = _default_interpret() if interpret is None else interpret
+    interp = default_interpret() if interpret is None else interpret
     nq = len(qkeys)
     qpad, qp = _pad_query_keys(qkeys)
     args = _stream_args(stream) + (aux.seg_flag, aux.end_pos, aux.valid, qpad)
@@ -740,7 +748,7 @@ def decode_refine_stream_multi(stream: PageStream, aux: RefineAux, qkeys,
                   values=stream.n_values, records=aux.n_records,
                   queries=nq, width=stream.width):
         lo, hi, mm, keep = fn(*args)
-        keep = np.array(keep[:nq, : aux.n_records])
+        keep = np.asarray(keep)[:nq, : aux.n_records].copy()
     keep[~np.asarray(qvalid, bool)] = False
     return MultiRefineResult(lo, hi, mm, keep)
 
@@ -814,9 +822,11 @@ def gather_stream_values(lo, hi, idx: np.ndarray, width: int, dtype,
     with obs.span("device.gather", cat="transfer", values=n,
                   on_device=bool(keep_on_device)):
         glo, ghi = fn(lo, hi, idx_pad)
-        coords = DeviceCoords(glo[:n], ghi[:n] if width == 64 else None, dtype)
+        ghi = ghi if width == 64 else None
         if not keep_on_device:
-            coords = coords.to_numpy()
+            # trim on the host: a device slice compiles one program per n
+            return DeviceCoords(glo, ghi, dtype).to_numpy()[:n]
+        coords = DeviceCoords(glo[:n], None if ghi is None else ghi[:n], dtype)
     return coords
 
 

@@ -229,18 +229,24 @@ def decode_blocks_ref(packed, widths, anchors, exc_idx, exc_val, exc_count):
 STREAM_BLOCK = 1024  # values per grid step of the stream kernel, one VPU tile
 
 
-def gather_tokens(words_u32: jnp.ndarray, offs: jnp.ndarray, nbits: jnp.ndarray):
-    """Gather token bits ``[offs, offs+nbits)`` from the LE word stream.
+def gather_words(words_u32: jnp.ndarray, offs: jnp.ndarray):
+    """The three-word window ``w0i .. w0i+2`` (``w0i = offs >> 5``) that
+    holds each token, as uint32 arrays shaped like ``offs``.
 
-    Returns ``(lo, hi)`` uint32 limbs. ``nbits`` must be in [1, 64] and
-    ``words_u32`` must carry >= 2 trailing spill words so the three-word
-    window ``w0i .. w0i+2`` is always in bounds.
+    ``words_u32`` must carry >= 2 trailing spill words so the window is
+    always in bounds. This is the stream's only data-dependent gather; the
+    decode kernel receives its result, since Mosaic lowers no 1-D gather.
     """
     words = words_u32.astype(jnp.uint32)
     w0i = offs >> 5
-    w0 = jnp.take(words, w0i, mode="clip")
-    w1 = jnp.take(words, w0i + 1, mode="clip")
-    w2 = jnp.take(words, w0i + 2, mode="clip")
+    return tuple(jnp.take(words, w0i + k, mode="clip") for k in range(3))
+
+
+def extract_tokens(w0, w1, w2, offs: jnp.ndarray, nbits: jnp.ndarray):
+    """Token bits ``[offs, offs+nbits)`` from each token's three-word window.
+
+    Returns ``(lo, hi)`` uint32 limbs; ``nbits`` must be in [1, 64].
+    """
     s = (offs & 31).astype(jnp.uint32)
     inv = (jnp.uint32(32) - s) & jnp.uint32(31)  # shift-by-32 is UB: mask + select
     lo = (w0 >> s) | jnp.where(s == 0, jnp.uint32(0), w1 << inv)
@@ -253,6 +259,12 @@ def gather_tokens(words_u32: jnp.ndarray, offs: jnp.ndarray, nbits: jnp.ndarray)
         nhi == 0, jnp.uint32(0), full >> ((jnp.uint32(32) - nhi) & jnp.uint32(31))
     )
     return lo & mask_lo, hi & mask_hi
+
+
+def gather_tokens(words_u32: jnp.ndarray, offs: jnp.ndarray, nbits: jnp.ndarray):
+    """Gather token bits ``[offs, offs+nbits)`` from the LE word stream
+    (:func:`gather_words` then :func:`extract_tokens`)."""
+    return extract_tokens(*gather_words(words_u32, offs), offs, nbits)
 
 
 def unzigzag_limbs(lo: jnp.ndarray, hi: jnp.ndarray):

@@ -2,14 +2,16 @@
 and the segmented per-record min/max scan of the fused decode→refine path.
 
 ``minmax``: grid is (n_pages, page_tiles): the page dimension is parallel,
-the tile dimension is sequential with VMEM scratch accumulation — pages of
-any size stream through a fixed (8, 128)-aligned VMEM tile, so the working
-set is constant regardless of page size.
+the tile dimension is sequential, accumulating per-lane extrema in the
+page's lane-dense ``(1, 128)`` output block — pages of any size stream
+through a fixed ``(16, 128)`` VMEM block, so the working set is constant
+regardless of page size.
 
 ``segminmax_blocks``: the record-granular sibling, structured exactly like
 the page-stream decode kernel in ``repro.kernels.fp_delta``: each grid step
-runs a block-local segmented min/max scan (log-step shifted combines on the
-VPU) over one block of 1024 order-key limb pairs; cross-block carries are
+runs a block-local segmented min/max scan (log-step rotate-and-combine on
+the VPU, :func:`repro.kernels.tile_scan.tile_scan`) over one ``(8, 128)``
+tile of 1024 order-key limb pairs; cross-block carries are
 stitched afterwards with one tiny associative scan over per-block summaries,
 keeping the grid embarrassingly parallel. The scan state per element is
 ``(min_lo, min_hi, max_lo, max_hi, seen_flag)`` with lexicographic uint32
@@ -23,30 +25,35 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .ref import _MAX_IDENT, _MIN_IDENT, minmax_seg_combine, segmented_minmax_scan
+from ..tile_scan import tile_scan
+from .ref import _MAX_IDENT, _MIN_IDENT, minmax_seg_combine
 
-_TILE = 2048  # values per grid step; multiple of (8, 128)
+_TILE = 2048  # values per grid step of the page min/max: one (16, 128) block
+_TILE_ROWS = _TILE // 128
 
 SEG_BLOCK = 1024  # values per grid step of the segmented scan, one VPU tile
 _BLOCK_2D = (8, 128)
 
 
 def _minmax_kernel(x_ref, min_ref, max_ref):
+    # per-lane partial extrema of the page, accumulated over its tiles in
+    # the lane-dense output block; the final 128-lane reduce is XLA's
     t = pl.program_id(1)
-    x = x_ref[...]
-    tile_min = jnp.min(x)
-    tile_max = jnp.max(x)
+    x = x_ref[0]
+    tile_min = jnp.min(x, axis=0, keepdims=True)
+    tile_max = jnp.max(x, axis=0, keepdims=True)
 
     @pl.when(t == 0)
     def _init():
-        min_ref[0, 0] = tile_min
-        max_ref[0, 0] = tile_max
+        min_ref[0] = tile_min
+        max_ref[0] = tile_max
 
     @pl.when(t > 0)
     def _acc():
-        min_ref[0, 0] = jnp.minimum(min_ref[0, 0], tile_min)
-        max_ref[0, 0] = jnp.maximum(max_ref[0, 0], tile_max)
+        min_ref[0] = jnp.minimum(min_ref[0], tile_min)
+        max_ref[0] = jnp.maximum(max_ref[0], tile_max)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -58,35 +65,35 @@ def minmax(x: jnp.ndarray, *, interpret: bool = True):
     n_pages, page_size = x.shape
     assert page_size % _TILE == 0, page_size
     tiles = page_size // _TILE
+    x3 = x.reshape(n_pages, page_size // 128, 128)
+    out_spec = pl.BlockSpec((1, 1, 128), lambda p, t: (p, 0, 0))
+    out_shape = jax.ShapeDtypeStruct((n_pages, 1, 128), x.dtype)
     mins, maxs = pl.pallas_call(
         _minmax_kernel,
         grid=(n_pages, tiles),
-        in_specs=[pl.BlockSpec((1, _TILE), lambda p, t: (p, t))],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda p, t: (p, 0)),
-            pl.BlockSpec((1, 1), lambda p, t: (p, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_pages, 1), x.dtype),
-            jax.ShapeDtypeStruct((n_pages, 1), x.dtype),
-        ],
+        in_specs=[pl.BlockSpec((1, _TILE_ROWS, 128), lambda p, t: (p, t, 0))],
+        out_specs=[out_spec, out_spec],
+        out_shape=[out_shape, out_shape],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(x)
-    return mins[:, 0], maxs[:, 0]
+    )(x3)
+    return jnp.min(mins, axis=(1, 2)), jnp.max(maxs, axis=(1, 2))
 
 
 # ---------------------------------------------------------- segmented minmax
 def _segminmax_kernel(klo_ref, khi_ref, flag_ref,
                       mnlo_ref, mnhi_ref, mxlo_ref, mxhi_ref, seen_ref):
-    klo = klo_ref[...].reshape(SEG_BLOCK).astype(jnp.uint32)
-    khi = khi_ref[...].reshape(SEG_BLOCK).astype(jnp.uint32)
-    flag = flag_ref[...].reshape(SEG_BLOCK) != 0
-    mnlo, mnhi, mxlo, mxhi, seen = segmented_minmax_scan(klo, khi, flag)
-    mnlo_ref[...] = mnlo.astype(jnp.int32).reshape(1, *_BLOCK_2D)
-    mnhi_ref[...] = mnhi.astype(jnp.int32).reshape(1, *_BLOCK_2D)
-    mxlo_ref[...] = mxlo.astype(jnp.int32).reshape(1, *_BLOCK_2D)
-    mxhi_ref[...] = mxhi.astype(jnp.int32).reshape(1, *_BLOCK_2D)
-    seen_ref[...] = seen.astype(jnp.int32).reshape(1, *_BLOCK_2D)
+    klo = klo_ref[0]
+    khi = khi_ref[0]
+    mnlo, mnhi, mxlo, mxhi, seen = tile_scan(
+        minmax_seg_combine, (klo, khi, klo, khi, flag_ref[0] != 0),
+        (_MIN_IDENT, _MIN_IDENT, _MAX_IDENT, _MAX_IDENT, False))
+    mnlo_ref[0] = mnlo
+    mnhi_ref[0] = mnhi
+    mxlo_ref[0] = mxlo
+    mxhi_ref[0] = mxhi
+    seen_ref[0] = seen.astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -102,22 +109,20 @@ def segminmax_blocks(key_lo, key_hi, flag, *, interpret: bool = True):
     ``ref.segment_minmax_ref``.
     """
     n_blocks = key_lo.shape[0]
-    kl = key_lo.reshape(n_blocks, *_BLOCK_2D)
-    kh = key_hi.reshape(n_blocks, *_BLOCK_2D)
-    fl = flag.reshape(n_blocks, *_BLOCK_2D)
+    tile = (n_blocks, *_BLOCK_2D)
     spec = pl.BlockSpec((1, *_BLOCK_2D), lambda b: (b, 0, 0))
-    shape = jax.ShapeDtypeStruct((n_blocks, *_BLOCK_2D), jnp.int32)
+    key = jax.ShapeDtypeStruct(tile, jnp.uint32)
     outs = pl.pallas_call(
         _segminmax_kernel,
         grid=(n_blocks,),
         in_specs=[spec, spec, spec],
         out_specs=[spec] * 5,
-        out_shape=[shape] * 5,
+        out_shape=[key] * 4 + [jax.ShapeDtypeStruct(tile, jnp.int32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(kl, kh, fl)
-    mnlo, mnhi, mxlo, mxhi = (
-        o.reshape(n_blocks, SEG_BLOCK).astype(jnp.uint32) for o in outs[:4]
-    )
+    )(key_lo.astype(jnp.uint32).reshape(tile),
+      key_hi.astype(jnp.uint32).reshape(tile), flag.reshape(tile))
+    mnlo, mnhi, mxlo, mxhi = (o.reshape(n_blocks, SEG_BLOCK) for o in outs[:4])
     seen = outs[4].reshape(n_blocks, SEG_BLOCK) != 0
     # Carry stitch: block b inherits the running min/max of the last open
     # segment before it — an exclusive segmented combine of the per-block

@@ -18,12 +18,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import default_interpret
 from . import kernel, ref
 from .kernel import _TILE, SEG_BLOCK
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def page_minmax(
@@ -37,7 +34,7 @@ def page_minmax(
         x = jnp.concatenate([x, jnp.broadcast_to(x[:, -1:], (n_pages, pad))], axis=1)
     if not use_pallas:
         return jax.jit(ref.minmax_ref)(x)
-    interp = _default_interpret() if interpret is None else interpret
+    interp = default_interpret() if interpret is None else interpret
     return kernel.minmax(x, interpret=interp)
 
 
@@ -85,18 +82,23 @@ def column_page_stats(values: np.ndarray, page_bounds: np.ndarray, **kw):
     if len(values) == 0 or empty.all():
         return out_min, out_max
     for lo, hi in _batch_spans(counts):
-        c = counts[lo:hi]
-        max_len = max(int(c.max()), 1)
+        # the batch shape is bucketed — rows to a power of two, columns to
+        # the kernel tile — so a writer's row groups share a few compiled
+        # shapes; padding rows repeat the first page and are dropped
+        rows = np.arange(lo, lo + (1 << (hi - lo - 1).bit_length()))
+        rows[rows >= hi] = lo
+        c = counts[rows]
+        max_len = -(-max(int(c.max()), 1) // _TILE) * _TILE
         # int32 positions + in-place clip keep the gather-index temporaries
         # within a small constant factor of the float32 batch itself
         pos = np.minimum(np.arange(max_len, dtype=np.int32)[None, :],
                          np.maximum(c - 1, 0).astype(np.int32)[:, None])
-        idx = bounds[lo:hi, None] + pos
+        idx = bounds[rows, None] + pos
         np.minimum(idx, len(values) - 1, out=idx)
         batch = values[idx]
         mn, mx = page_minmax(jnp.asarray(batch), **kw)
-        out_min[lo:hi] = np.asarray(mn)
-        out_max[lo:hi] = np.asarray(mx)
+        out_min[lo:hi] = np.asarray(mn)[: hi - lo]
+        out_max[lo:hi] = np.asarray(mx)[: hi - lo]
     out_min[empty] = np.inf
     out_max[empty] = -np.inf
     return out_min, out_max
@@ -163,5 +165,5 @@ def segment_minmax(key_lo, key_hi, flag, *, use_pallas: bool = True,
     """
     if not use_pallas:
         return ref.segment_minmax_ref(key_lo, key_hi, flag)
-    interp = _default_interpret() if interpret is None else interpret
+    interp = default_interpret() if interpret is None else interpret
     return kernel.segminmax_blocks(key_lo, key_hi, flag, interpret=interp)

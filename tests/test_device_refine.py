@@ -517,6 +517,25 @@ def test_column_page_stats_batched_matches_loop(rng):
     assert len(mn0) == 0 and len(mx0) == 0
 
 
+@pytest.mark.parametrize("budget", [2000, 8000])
+def test_column_page_stats_split_batches(rng, monkeypatch, budget):
+    """Budget-split batches, each bucketed to pow2 rows with padding rows,
+    still equal the per-page reference on every page."""
+    from repro.kernels.minmax import column_page_stats
+    from repro.kernels.minmax import ops as mm_ops
+
+    monkeypatch.setattr(mm_ops, "_BATCH_BUDGET", budget)
+    counts = rng.integers(0, 400, 45)
+    bounds = np.concatenate([[0], np.cumsum(counts)]).astype(np.int64)
+    values = rng.normal(0, 100, int(bounds[-1])).astype(np.float32)
+    assert len(mm_ops._batch_spans(counts)) > 1
+    mn, mx = column_page_stats(values, bounds)
+    for i, c in enumerate(counts):
+        chunk = values[bounds[i]: bounds[i + 1]]
+        want = (chunk.min(), chunk.max()) if c else (np.inf, -np.inf)
+        assert (mn[i], mx[i]) == want, i
+
+
 # ------------------------------------------------- adversarial property tests
 def _refine_roundtrip(seed):
     rng = np.random.default_rng(seed)

@@ -385,6 +385,42 @@ def test_scanner_retry_policy_exhausts_to_error(lake):
     assert ei.value.shard_index == 0
 
 
+@pytest.mark.parametrize("policy", ["retry", "skip"])
+@pytest.mark.parametrize("fault", ["run", "compile"])
+def test_scanner_device_errors_bypass_policy(lake, monkeypatch, policy, fault):
+    """A device program that fails to compile or run is no fault of the
+    shard: under retry/skip it propagates from the first attempt instead
+    of turning into a retried or skipped shard and a short answer."""
+    jax = pytest.importorskip("jax")
+    import repro.kernels.fp_delta as fpd
+    from repro.kernels import DeviceCompileError
+    from repro.kernels.fp_delta import ops as fpd_ops
+
+    root, _, _ = lake
+    calls = []
+    if fault == "run":
+        def launch(*args, **kw):
+            calls.append(1)
+            raise jax.errors.JaxRuntimeError("INTERNAL: injected device fault")
+
+        monkeypatch.setattr(fpd, "decode_refine_stream", launch)
+        expected = jax.errors.JaxRuntimeError
+    else:
+        class Refused:  # a lowering refusal surfaces as ValueError
+            def lower(self, *args, **kw):
+                calls.append(1)
+                raise ValueError("injected lowering refusal")
+
+        monkeypatch.setattr(fpd_ops, "_COMPILED", {})
+        monkeypatch.setattr(fpd_ops, "_refine_jit", lambda *a: Refused())
+        expected = DeviceCompileError
+    sc = SpatialDatasetScanner(root, on_error=policy, shard_retries=2)
+    with pytest.raises(expected):
+        sc.scan(bbox=(-100.0, -100.0, 100.0, 100.0), refine=True,
+                device="jax", parallel=False)
+    assert len(calls) == 1  # first shard, first attempt: no retry, no skip
+
+
 def test_scanner_rejects_unknown_policy(lake):
     root, _, _ = lake
     with pytest.raises(ValueError):
