@@ -526,21 +526,61 @@ class SpatialParquetReader:
     def _decode_run_extras(self, src, extra_pages, extra_all, we: int,
                            p0: int, p1: int, stats: ReadStats) -> None:
         """Decode one run's extra-column pages into the preallocated columns
-        at record cursor ``we``."""
-        for k, ep in extra_pages.items():
-            wk = we
+        at record cursor ``we`` (an ``rg.extras`` span; each column's page
+        checksums are one ``rg.crc`` child, checked before any decode)."""
+        with obs.span("rg.extras", cat="decode", pages=p1 - p0):
+            for k, ep in extra_pages.items():
+                with obs.span("rg.crc", cat="plan", pages=p1 - p0):
+                    pages = []
+                    for p in range(p0, p1):
+                        meta = PageMeta.from_dict(ep[p])
+                        pages.append((meta, self._checked_blob(
+                            src, meta.offset, meta.nbytes, meta.crc, stats,
+                            f"extra column {k!r} page {p}")))
+                wk = we
+                for meta, blob in pages:
+                    decode_page(
+                        blob, meta,
+                        np.dtype(self.extra_schema[k]), self.codec,
+                        out=extra_all[k][wk : wk + meta.count],
+                    )
+                    stats.bytes_read += meta.nbytes
+                    wk += meta.count
+
+    def _coord_pages(self, src, rg, rg_i: int, base: int, p0: int, p1: int,
+                     stats: ReadStats) -> list[tuple]:
+        """``(meta_x, blob_x, meta_y, blob_y)`` of pages ``[p0, p1)`` of one
+        row group, every blob checksum-verified (one ``rg.crc`` span):
+        checksums gate the launch chain, so a corrupt page is caught here,
+        before any plan or Pallas kernel sees it."""
+        idx = self.index
+        xp, yp = rg["x_pages"], rg["y_pages"]
+        out = []
+        with obs.span("rg.crc", cat="plan", pages=p1 - p0):
             for p in range(p0, p1):
-                meta = PageMeta.from_dict(ep[p])
-                blob = self._checked_blob(
-                    src, meta.offset, meta.nbytes, meta.crc, stats,
-                    f"extra column {k!r} page {p}")
-                decode_page(
-                    blob, meta,
-                    np.dtype(self.extra_schema[k]), self.codec,
-                    out=extra_all[k][wk : wk + meta.count],
-                )
-                stats.bytes_read += meta.nbytes
-                wk += meta.count
+                j = base + p
+                meta_x = PageMeta.from_dict(xp[p])
+                meta_y = PageMeta.from_dict(yp[p])
+                out.append((
+                    meta_x,
+                    self._checked_blob(
+                        src, int(idx.x_offset[j]), int(idx.x_nbytes[j]),
+                        meta_x.crc, stats, f"x page {p} of row group {rg_i}"),
+                    meta_y,
+                    self._checked_blob(
+                        src, int(idx.y_offset[j]), int(idx.y_nbytes[j]),
+                        meta_y.crc, stats, f"y page {p} of row group {rg_i}"),
+                ))
+        return out
+
+    def _stream_plans(self, pages, plans: list) -> None:
+        """Append the x and y stream plan of each verified page to
+        ``plans`` (one ``rg.stream_plan`` span)."""
+        dtype = self.coord_dtype
+        with obs.span("rg.stream_plan", cat="plan", pages=len(pages)):
+            for meta_x, blob_x, meta_y, blob_y in pages:
+                plans.append(page_stream_plan(blob_x, meta_x, dtype, self.codec))
+                plans.append(page_stream_plan(blob_y, meta_y, dtype, self.codec))
 
     def _iter_sources(self, items, coalesce: bool):
         """Yield ``(item, src)`` per hit row group, double-buffering reads.
@@ -651,7 +691,6 @@ class SpatialParquetReader:
             # process-wide CPU per scanned GB: the GPU-layout-v2 ROADMAP
             # metric (how much host planning/decode a scan still costs)
             obs.gauge("scan.host_cpu_s_per_gb", cpu / scanned_gb)
-            obs.observe("scan.host_cpu_s_per_gb_hist", cpu / scanned_gb)
         obs.count("pruned.page_bytes",
                   max(0, stats.bytes_total - stats.bytes_read))
         obs.fold_read_stats(stats)
@@ -825,7 +864,7 @@ class SpatialParquetReader:
         if filter is not None:
             attr = (filter.mask(extras) if we
                     else np.zeros(0, bool))
-            if we:
+            if we and obs.enabled():
                 obs.observe("filter.selectivity", float(attr.sum()) / we)
             keep_mask = attr if keep_mask is None else keep_mask & attr
         if keep_mask is not None:
@@ -904,7 +943,6 @@ class SpatialParquetReader:
         src_iter = self._iter_sources(items, coalesce)
         try:
             for (rg_i, rg, runs, base, extra_pages, _ranges), src in src_iter:
-                xp, yp = rg["x_pages"], rg["y_pages"]
                 lv = self._decode_rg_levels(src, rg, stats)
                 rec_vcounts_rg = lv.record_value_counts()
                 we0 = we  # this row group's record span in the extra columns
@@ -920,25 +958,9 @@ class SpatialParquetReader:
                         r0 = int(idx.rec_start[j0])
                         r1 = int(idx.rec_start[j1] + idx.rec_count[j1])
                         stats.records_scanned += r1 - r0
-                        for p in range(p0, p1):
-                            j = base + p
-                            meta_x = PageMeta.from_dict(xp[p])
-                            meta_y = PageMeta.from_dict(yp[p])
-                            # checksums gate the launch chain: a corrupt page
-                            # is caught here, before any plan or Pallas
-                            # kernel sees it
-                            blob_x = self._checked_blob(
-                                src, int(idx.x_offset[j]), int(idx.x_nbytes[j]),
-                                meta_x.crc, stats,
-                                f"x page {p} of row group {rg_i}")
-                            blob_y = self._checked_blob(
-                                src, int(idx.y_offset[j]), int(idx.y_nbytes[j]),
-                                meta_y.crc, stats,
-                                f"y page {p} of row group {rg_i}")
-                            plans.append(page_stream_plan(
-                                blob_x, meta_x, dtype, self.codec))
-                            plans.append(page_stream_plan(
-                                blob_y, meta_y, dtype, self.codec))
+                        self._stream_plans(self._coord_pages(
+                            src, rg, rg_i, base, p0, p1, stats), plans)
+                        for j in range(j0, j1 + 1):
                             lo_loc = local_base + int(idx.rec_start[j]) - r0
                             pairs.append((lo_loc, lo_loc + int(idx.rec_count[j])))
                         stats.bytes_read += int(
@@ -991,16 +1013,19 @@ class SpatialParquetReader:
                     with obs.span("rg.launch", cat="device", rg=rg_i,
                                   kind="refine" if do_refine else "decode",
                                   pairs=len(cpairs)):
-                        stream = build_page_stream(cplans)
-                        aux = build_refine_aux(
-                            stream, [(a - rl, b - rl) for a, b in cpairs], vc)
-                        if attr_c is not None and do_refine:
-                            # the device record mask is valid ∧ bbox; AND-ing
-                            # the attribute mask into a fresh copy of valid
-                            # makes it bbox ∧ attrs in the same launch
-                            v2 = aux.valid.copy()
-                            v2[:len(attr_c)] &= attr_c
-                            aux = dc_replace(aux, valid=v2)
+                        with obs.span("launch.build", cat="plan"):
+                            stream = build_page_stream(cplans)
+                            aux = build_refine_aux(
+                                stream, [(a - rl, b - rl) for a, b in cpairs],
+                                vc)
+                            if attr_c is not None and do_refine:
+                                # the device record mask is valid ∧ bbox;
+                                # AND-ing the attribute mask into a fresh copy
+                                # of valid makes it bbox ∧ attrs in the same
+                                # launch
+                                v2 = aux.valid.copy()
+                                v2[:len(attr_c)] &= attr_c
+                                aux = dc_replace(aux, valid=v2)
                         if do_refine:
                             res = decode_refine_stream(stream, aux, bbox)
                             keep_c, lo_d, hi_d = res.keep, res.lo, res.hi
@@ -1052,7 +1077,7 @@ class SpatialParquetReader:
         extras = {k: v[:we] for k, v in extra_all.items()}
         if do_compact and geo is not None:
             extras = {k: v[keep_all] for k, v in extras.items()}
-        if filter is not None and we:
+        if filter is not None and we and obs.enabled():
             obs.observe("filter.selectivity", float(keep_all.sum()) / we)
         stats.records_returned = geo.n_records if geo is not None else (
             len(next(iter(extras.values()))) if extras else 0
@@ -1105,26 +1130,15 @@ class SpatialParquetReader:
                                         + idx.y_nbytes[j0 : j1 + 1].sum())
             rec0 = int(idx.rec_start[base]) if n_pages else 0
 
-            def coord_blobs(p):
-                j = base + p
-                meta_x = PageMeta.from_dict(rg["x_pages"][p])
-                meta_y = PageMeta.from_dict(rg["y_pages"][p])
-                blob_x = self._checked_blob(
-                    src, int(idx.x_offset[j]), int(idx.x_nbytes[j]),
-                    meta_x.crc, stats, f"x page {p} of row group {rg_i}")
-                blob_y = self._checked_blob(
-                    src, int(idx.y_offset[j]), int(idx.y_nbytes[j]),
-                    meta_y.crc, stats, f"y page {p} of row group {rg_i}")
-                return meta_x, blob_x, meta_y, blob_y
-
             if device == "cpu":
                 total_vals = int(idx.count[base : base + n_pages].sum())
                 x_all = np.empty(total_vals, self.coord_dtype)
                 y_all = np.empty(total_vals, self.coord_dtype)
                 w = 0
                 with obs.span("rg.decode", cat="decode", rg=rg_i, device="cpu"):
-                    for p in range(n_pages):
-                        meta_x, blob_x, meta_y, blob_y = coord_blobs(p)
+                    pages = self._coord_pages(src, rg, rg_i, base, 0, n_pages,
+                                              stats)
+                    for p, (meta_x, blob_x, meta_y, blob_y) in enumerate(pages):
                         cnt = int(idx.count[base + p])
                         decode_page(blob_x, meta_x, self.coord_dtype,
                                     self.codec, out=x_all[w : w + cnt])
@@ -1143,13 +1157,9 @@ class SpatialParquetReader:
             plans: list = []
             pairs: list[tuple[int, int]] = []
             with obs.span("rg.plan", cat="plan", rg=rg_i, pages=n_pages):
-                for p in range(n_pages):
-                    meta_x, blob_x, meta_y, blob_y = coord_blobs(p)
-                    plans.append(page_stream_plan(
-                        blob_x, meta_x, self.coord_dtype, self.codec))
-                    plans.append(page_stream_plan(
-                        blob_y, meta_y, self.coord_dtype, self.codec))
-                    j = base + p
+                self._stream_plans(self._coord_pages(
+                    src, rg, rg_i, base, 0, n_pages, stats), plans)
+                for j in range(base, base + n_pages):
                     r0 = int(idx.rec_start[j]) - rec0
                     pairs.append((r0, r0 + int(idx.rec_count[j])))
             chunks: list[RowGroupChunk] = []

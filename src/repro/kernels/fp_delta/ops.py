@@ -252,7 +252,9 @@ def build_page_stream(plans) -> PageStream:
     value becomes either an *anchor* (page first value, escaped raw value,
     or any raw-mode value — token width W, starts a segment) or an inline
     n-bit delta token. Total payload must stay under ``_MAX_LAUNCH_BITS``
-    (use :func:`decode_pages`, which chunks automatically).
+    (use :func:`decode_pages`, which chunks automatically). The counters
+    ``launch.values`` and ``launch.values_padded`` add the stream's values
+    and the decode lanes it launches (``n_blocks * STREAM_BLOCK``).
     """
     plans = list(plans)
     widths = {p.width for p in plans if p.n_values}
@@ -310,6 +312,8 @@ def build_page_stream(plans) -> PageStream:
     n = int(sum(counts))
     n_blocks = _pow2_bucket(-(-max(n, 1) // STREAM_BLOCK), 1)
     pad = n_blocks * STREAM_BLOCK
+    obs.count("launch.values", n)
+    obs.count("launch.values_padded", pad)
     off_a = np.zeros(pad, np.int64)
     nb_a = np.full(pad, width, np.int64)   # padding: W-bit anchors at bit 0
     an_a = np.ones(pad, np.int64)
@@ -344,6 +348,21 @@ def _stream_args(stream: PageStream) -> tuple:
     return (stream.words32, stream.tok_off, stream.nbits, stream.anchor)
 
 
+def _decode_launch(stream: PageStream, use_pallas: bool,
+                   interpret: bool | None):
+    """The compiled stream decode and its arguments."""
+    interp = default_interpret() if interpret is None else interpret
+    args = _stream_args(stream)
+    key = ("limbs", stream.words32.shape[0], stream.tok_off.shape[0],
+           use_pallas, interp)
+    return _aot(key, _limbs_jit(use_pallas, interp), args), args
+
+
+def _launch_span(stream: PageStream):
+    return obs.span("device.decode_launch", cat="device",
+                    values=stream.n_values, width=stream.width)
+
+
 def decode_stream_device(stream: PageStream, *, use_pallas: bool = True,
                          interpret: bool | None = None):
     """Decode a built stream, keeping the result device-resident.
@@ -352,13 +371,8 @@ def decode_stream_device(stream: PageStream, *, use_pallas: bool = True,
     ``n_blocks * STREAM_BLOCK`` (tail is padding; ``hi`` is zero for 32-bit
     streams). The bit patterns equal the host decode exactly.
     """
-    interp = default_interpret() if interpret is None else interpret
-    args = _stream_args(stream)
-    key = ("limbs", stream.words32.shape[0], stream.tok_off.shape[0],
-           use_pallas, interp)
-    fn = _aot(key, _limbs_jit(use_pallas, interp), args)
-    with obs.span("device.decode_launch", cat="device",
-                  values=stream.n_values, width=stream.width):
+    fn, args = _decode_launch(stream, use_pallas, interpret)
+    with _launch_span(stream):
         return fn(*args)
 
 
@@ -371,11 +385,13 @@ def decode_page_stream(stream: PageStream, *, use_pallas: bool = True,
     dtype = np.float32 if stream.width == 32 else np.float64
     if n == 0:
         return np.zeros(0, dtype)
-    lo, hi = decode_stream_device(
-        stream, use_pallas=use_pallas, interpret=interpret)
-    # trim on the host: a device slice compiles one program per length
-    return DeviceCoords(lo, hi if stream.width == 64 else None,
-                        np.dtype(dtype)).to_numpy()[:n]
+    fn, args = _decode_launch(stream, use_pallas, interpret)
+    with _launch_span(stream):
+        lo, hi = fn(*args)
+        with obs.span("device.wait", cat="device"):
+            # trim on the host: a device slice compiles one program per length
+            return DeviceCoords(lo, hi if stream.width == 64 else None,
+                                np.dtype(dtype)).to_numpy()[:n]
 
 
 def _plan_bits(p: FPDeltaPlan) -> int:
@@ -623,7 +639,8 @@ def decode_refine_stream(stream: PageStream, aux: RefineAux, bbox, *,
                   values=stream.n_values, records=aux.n_records,
                   width=stream.width):
         lo, hi, keep = fn(*args)
-        keep = np.asarray(keep)[: aux.n_records]
+        with obs.span("device.wait", cat="device"):
+            keep = np.asarray(keep)[: aux.n_records]
     return RefineResult(lo, hi, keep)
 
 
@@ -748,7 +765,8 @@ def decode_refine_stream_multi(stream: PageStream, aux: RefineAux, qkeys,
                   values=stream.n_values, records=aux.n_records,
                   queries=nq, width=stream.width):
         lo, hi, mm, keep = fn(*args)
-        keep = np.asarray(keep)[:nq, : aux.n_records].copy()
+        with obs.span("device.wait", cat="device"):
+            keep = np.asarray(keep)[:nq, : aux.n_records].copy()
     keep[~np.asarray(qvalid, bool)] = False
     return MultiRefineResult(lo, hi, mm, keep)
 
@@ -775,7 +793,9 @@ def refine_minmax_multi(minmax, valid, qkeys, qvalid, *, width: int,
     fn = _aot(key, _minmax_keep_jit(width), args)
     with obs.span("device.refine_cached", cat="device",
                   records=n_records, queries=nq, width=width):
-        keep = np.array(np.asarray(fn(*args))[:nq, :n_records])
+        keep = fn(*args)
+        with obs.span("device.wait", cat="device"):
+            keep = np.array(np.asarray(keep)[:nq, :n_records])
     keep[~np.asarray(qvalid, bool)] = False
     return keep
 
@@ -825,7 +845,8 @@ def gather_stream_values(lo, hi, idx: np.ndarray, width: int, dtype,
         ghi = ghi if width == 64 else None
         if not keep_on_device:
             # trim on the host: a device slice compiles one program per n
-            return DeviceCoords(glo, ghi, dtype).to_numpy()[:n]
+            with obs.span("device.wait", cat="device"):
+                return DeviceCoords(glo, ghi, dtype).to_numpy()[:n]
         coords = DeviceCoords(glo[:n], None if ghi is None else ghi[:n], dtype)
     return coords
 

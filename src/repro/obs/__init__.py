@@ -19,6 +19,12 @@ object is allocated, ever), the recorders return immediately, and
 syscall sequence are bit-identical with tracing on or off (enforced by
 ``tests/test_obs.py``).
 
+While enabled, every span also records its thread's CPU time (the
+``cpu_us`` arg, summed into the counter ``cpu_ns.<span name>``) and opens a
+``jax.profiler.TraceAnnotation`` of the same name, so a ``jax.profiler``
+trace holds the host stages beside the device ops on the profiler's own
+clock. ``jax.profiler`` is imported by :func:`enable`, not here.
+
 Span context crosses threads explicitly: :func:`submit` wraps the worker
 callable in ``contextvars.copy_context().run`` so spans opened on scanner
 workers / the reader's prefetch thread parent under the span open at submit
@@ -72,8 +78,19 @@ def enable(*, reset: bool = True) -> Tracer:
         _tracer = Tracer()
     if reset or _registry is None:
         _registry = MetricsRegistry()
+    _tracer.registry = _registry
+    _tracer.annotation = _trace_annotation()
     _enabled = True
     return _tracer
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, or None where JAX is missing."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
 
 
 def disable() -> None:

@@ -16,13 +16,20 @@ form), loadable in Perfetto / ``chrome://tracing`` as-is:
 * spans → complete events (``"ph": "X"``) with microsecond ``ts``/``dur``,
   the real OS thread id as ``tid``, and ``args`` carrying the structured
   attributes plus ``span_id``/``parent_id`` (explicit nesting, robust across
-  thread hand-offs where timestamp containment alone is ambiguous);
+  thread hand-offs where timestamp containment alone is ambiguous) and
+  ``cpu_us``, the CPU time of the span's own thread over the span (wall
+  minus ``cpu_us`` is what the thread waited: GIL, I/O, device);
 * :meth:`Tracer.instant` → instant events (``"ph": "i"``) for point
   occurrences (a retry, a backoff, a skipped shard);
 * thread names → ``"ph": "M"`` ``thread_name`` metadata events.
 
-This module holds no global state and imports only the stdlib; the enabled
-flag and the no-op fast path live in :mod:`repro.obs`.
+A tracer may carry a metrics ``registry`` (each span then adds its CPU time
+to the counter ``cpu_ns.<name>``) and an ``annotation`` factory (each span
+then also opens one, named as the span: :func:`repro.obs.enable` passes
+``jax.profiler.TraceAnnotation``, which puts the spans in a profiler trace
+beside the device ops, on the profiler's clock). This module holds no
+global state and imports only the stdlib; the enabled flag and the no-op
+fast path live in :mod:`repro.obs`.
 """
 
 from __future__ import annotations
@@ -76,7 +83,7 @@ class Span:
     """One timed, attributed stage; records itself on ``__exit__``."""
 
     __slots__ = ("tracer", "name", "cat", "args", "span_id", "parent_id",
-                 "_t0", "_token")
+                 "_t0", "_c0", "_token", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
         self.tracer = tracer
@@ -91,13 +98,23 @@ class Span:
         if parent is not None:
             self.parent_id = parent.span_id
         self._token = _CURRENT.set(self)
+        ann = self.tracer.annotation
+        if ann is not None:
+            ann = ann(self.name)
+            ann.__enter__()
+        self._ann = ann
+        # wall clock outside the thread's CPU clock: cpu_us <= dur
         self._t0 = time.perf_counter_ns()
+        self._c0 = time.thread_time_ns()
         return self
 
     def __exit__(self, *exc):
+        c1 = time.thread_time_ns()
         t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         _CURRENT.reset(self._token)
-        self.tracer._complete(self, self._t0, t1 - self._t0)
+        self.tracer._complete(self, self._t0, t1 - self._t0, c1 - self._c0)
         return False
 
     def add(self, **args):
@@ -115,6 +132,8 @@ class Tracer:
         self._threads: dict[int, str] = {}
         self.epoch_ns = time.perf_counter_ns()
         self.pid = os.getpid()
+        self.registry = None     # a MetricsRegistry: cpu_ns.<name> counters
+        self.annotation = None   # a TraceAnnotation-like factory
 
     # ------------------------------------------------------------- recording
     def _tid(self) -> int:
@@ -124,7 +143,8 @@ class Tracer:
             self._threads[tid] = t.name
         return tid
 
-    def _complete(self, span: Span, t0_ns: int, dur_ns: int) -> None:
+    def _complete(self, span: Span, t0_ns: int, dur_ns: int,
+                  cpu_ns: int = 0) -> None:
         ev = {
             "name": span.name,
             "cat": span.cat,
@@ -134,10 +154,12 @@ class Tracer:
             "pid": self.pid,
             "tid": self._tid(),
             "args": dict(span.args, span_id=span.span_id,
-                         parent_id=span.parent_id),
+                         parent_id=span.parent_id, cpu_us=cpu_ns / 1000.0),
         }
         with self._lock:
             self._events.append(ev)
+        if self.registry is not None:
+            self.registry.counter("cpu_ns." + span.name).inc(cpu_ns)
 
     def instant(self, name: str, cat: str = "event", **args) -> None:
         """Record a point event (``"ph": "i"``, thread-scoped)."""

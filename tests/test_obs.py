@@ -38,7 +38,10 @@ def rng():
 
 @pytest.fixture(autouse=True)
 def _obs_off():
-    """Every test starts and ends with telemetry disabled."""
+    """Every test starts with telemetry disabled and an empty tracer and
+    registry (another test file in the same process may have left its
+    own), and ends with telemetry disabled."""
+    obs.enable()
     obs.disable()
     yield
     obs.disable()
@@ -380,3 +383,233 @@ def test_raise_policy_attaches_partial_stats(lake):
         sc.scan()
     cause = ei.value.__cause__
     assert getattr(cause, "spqf_source_stats").retries == 7
+
+
+# ------------------------------------------ step spans, CPU time, annotations
+@pytest.fixture
+def extras_lake(rng, tmp_path):
+    root = str(tmp_path / "xlake")
+    os.makedirs(root)
+    n = 6000
+    write_dataset(root, columns=_point_cols(rng, n), n_shards=4,
+                  page_values=512,
+                  extra={"tag": rng.integers(0, 50, n).astype(np.int32),
+                         "w": rng.uniform(0, 1, n)})
+    return root
+
+
+def _traced_scan(root, **kw):
+    sc = SpatialDatasetScanner(root)
+    tracer = obs.enable()
+    try:
+        out = sc.scan(bbox=(-60.0, -60.0, 60.0, 60.0), refine=True,
+                      device="jax", **kw)
+    finally:
+        obs.disable()
+    return tracer, out
+
+
+def _children(tracer):
+    kids: dict = {}
+    for e in tracer.spans():
+        kids.setdefault(e["args"]["parent_id"], []).append(e)
+    return kids
+
+
+def test_plan_step_spans_nest_under_rg_plan(extras_lake):
+    tracer, _ = _traced_scan(extras_lake)
+    ids = {e["args"]["span_id"]: e for e in tracer.spans()}
+    kids = _children(tracer)
+    plans = tracer.spans("rg.plan")
+    assert plans
+    for name in ("rg.stream_plan", "rg.extras"):
+        got = tracer.spans(name)
+        assert got and all(ids[e["args"]["parent_id"]]["name"] == "rg.plan"
+                           for e in got), name
+    # coordinate CRCs under rg.plan, the extras' own under rg.extras
+    crc_parents = {ids[e["args"]["parent_id"]]["name"]
+                   for e in tracer.spans("rg.crc")}
+    assert crc_parents == {"rg.plan", "rg.extras"}
+    for p in plans:
+        steps = [k for k in kids[p["args"]["span_id"]]
+                 if k["name"] in ("rg.crc", "rg.stream_plan", "rg.extras")]
+        assert {k["name"] for k in steps} == {"rg.crc", "rg.stream_plan",
+                                              "rg.extras"}
+        assert sum(k["dur"] for k in steps) <= p["dur"]
+
+
+def test_device_wait_nests_under_launches(extras_lake):
+    from repro.core.fp_delta import fp_delta_encode, fp_delta_plan
+    from repro.kernels.fp_delta import build_page_stream, decode_page_stream
+
+    tracer, _ = _traced_scan(extras_lake)
+    ids = {e["args"]["span_id"]: e for e in tracer.spans()}
+    kids = _children(tracer)
+    refines = tracer.spans("device.refine_launch")
+    assert refines
+    for e in refines:
+        assert [k["name"] for k in kids[e["args"]["span_id"]]] == ["device.wait"]
+    waits = {ids[e["args"]["parent_id"]]["name"]
+             for e in tracer.spans("device.wait")}
+    assert waits == {"device.refine_launch", "device.gather"}
+    # a plain stream decode: the launch span holds the wait for its values
+    vals = np.cumsum(np.full(3000, 0.25))
+    stream = build_page_stream([fp_delta_plan(fp_delta_encode(vals)[0],
+                                              len(vals), np.float64)])
+    tracer = obs.enable()
+    try:
+        out = decode_page_stream(stream)
+    finally:
+        obs.disable()
+    assert np.array_equal(out.view(np.int64), vals.view(np.int64))
+    (launch,) = tracer.spans("device.decode_launch")
+    (wait,) = tracer.spans("device.wait")
+    assert wait["args"]["parent_id"] == launch["args"]["span_id"]
+
+
+def test_span_cpu_time_and_counters(extras_lake):
+    tracer, _ = _traced_scan(extras_lake)
+    spans = tracer.spans()
+    for e in spans:
+        assert 0 <= e["args"]["cpu_us"] <= e["dur"] + 1000.0, e["name"]
+    counters = obs.snapshot()["counters"]
+    names = {e["name"] for e in spans}
+    assert {k for k in counters if k.startswith("cpu_ns.")} == \
+        {f"cpu_ns.{n}" for n in names}
+    for n in names:
+        mine = tracer.spans(n)
+        total_us = sum(e["args"]["cpu_us"] for e in mine)
+        assert abs(counters[f"cpu_ns.{n}"] / 1e3 - total_us) <= len(mine), n
+
+
+def test_launch_padding_counters():
+    from repro.core.fp_delta import fp_delta_encode, fp_delta_plan
+    from repro.kernels.fp_delta import build_page_stream
+
+    def plan(n):
+        x = np.cumsum(np.full(n, 0.5))
+        return fp_delta_plan(fp_delta_encode(x)[0], n, np.float64)
+
+    obs.enable()
+    try:
+        # 3300 values: ceil(3300 / 1024) = 4 blocks, a power of two
+        build_page_stream([plan(1000), plan(300), plan(2000)])
+        c = obs.snapshot()["counters"]
+        assert (c["launch.values"], c["launch.values_padded"]) == (3300, 4096)
+        # 4100 values: 5 blocks, bucketed up to 8 (8192 lanes)
+        build_page_stream([plan(4100)])
+    finally:
+        obs.disable()
+    c = obs.snapshot()["counters"]
+    assert c["launch.values"] == 3300 + 4100
+    assert c["launch.values_padded"] == 4096 + 8192
+
+
+def test_tracing_off_scan_identical_and_registry_empty(extras_lake):
+    from repro.core.filters import Range
+
+    opened = []
+
+    class Recorder:
+        def __init__(self, name):
+            opened.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    kw = dict(bbox=(-60.0, -60.0, 60.0, 60.0), refine=True, device="jax",
+              filter=Range("tag", 5, 30))
+    _, traced = _traced_scan(extras_lake, filter=kw["filter"])
+    # a fresh, empty tracer and registry, then a scan with tracing off
+    tracer = obs.enable()
+    obs.disable()
+    tracer.annotation = Recorder
+    g, e, _ = SpatialDatasetScanner(extras_lake).scan(**kw)
+    assert _fingerprint(g, e) == _fingerprint(traced[0], traced[1])
+    assert obs.snapshot() == {"counters": {}, "gauges": {}, "histograms": {}}
+    assert tracer.events == [] and opened == []
+    # the same hook opens one annotation per span once tracing is on
+    obs.enable(reset=False).annotation = Recorder
+    with obs.span("probe"):
+        pass
+    obs.disable()
+    assert opened == ["probe"]
+
+
+def test_spans_on_profiler_clock(extras_lake, tmp_path):
+    """Every obs span appears as a TraceAnnotation on a host plane of a
+    ``jax.profiler`` trace, and the benchmark's clock mapping (one
+    perf_counter -> wall offset sampled as the trace starts) puts each
+    span's start within 1 ms of its annotation's."""
+    import glob
+    import time
+
+    import jax
+    from jax.profiler import ProfileData
+
+    sc = SpatialDatasetScanner(extras_lake)
+    bbox = (-60.0, -60.0, 60.0, 60.0)
+    sc.scan(bbox=bbox, refine=True, device="jax")   # compile off the trace
+    trace_dir = str(tmp_path / "trace")
+    tracer = obs.enable()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    try:
+        perf_to_wall = time.time_ns() - time.perf_counter_ns()
+        sc.scan(bbox=bbox, refine=True, device="jax")
+    finally:
+        jax.profiler.stop_trace()
+        obs.disable()
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    start_ns, host = None, {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "Task Environment":
+            start_ns = int(dict(plane.stats)["profile_start_time"])
+        elif plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    host.setdefault(e.name, []).append(int(e.start_ns))
+    shift = tracer.epoch_ns + perf_to_wall - start_ns
+    names = {e["name"] for e in tracer.spans()}
+    assert {"rg.plan", "rg.crc", "launch.build", "device.wait"} <= names
+    for name in names:
+        ours = sorted(int(e["ts"] * 1e3) + shift for e in tracer.spans(name))
+        theirs = sorted(host.get(name, []))
+        assert len(ours) == len(theirs), name
+        gap = max(abs(a - b) for a, b in zip(ours, theirs))
+        assert gap < 1_000_000, (name, gap)
+
+
+def test_obs_imports_and_traces_without_jax():
+    """``repro.obs`` needs only the stdlib and numpy: with ``jax`` made
+    unimportable it imports, enables (no annotations) and records spans."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "class Block:\n"
+        "    def find_spec(self, name, path=None, target=None):\n"
+        "        if name.split('.')[0] == 'jax':\n"
+        "            raise ImportError('jax blocked')\n"
+        "sys.meta_path.insert(0, Block())\n"
+        "from repro import obs\n"
+        "tracer = obs.enable()\n"
+        "assert tracer.annotation is None\n"
+        "with obs.span('a'):\n"
+        "    pass\n"
+        "obs.disable()\n"
+        "assert [e['name'] for e in tracer.spans()] == ['a']\n"
+        "assert 'cpu_ns.a' in obs.snapshot()['counters']\n"
+        "assert not any(m.split('.')[0] == 'jax' for m in sys.modules)\n"
+    )
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, PYTHONPATH=src)
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
