@@ -136,11 +136,6 @@ def marker_candidates(words: np.ndarray, n: int) -> np.ndarray:
     return nzw[hot >> 6] * 64 + (hot & 63)
 
 
-def read_one(words: np.ndarray, start_bit: int, width: int) -> int:
-    """Scalar read of a single value (header parsing)."""
-    return int(unpack_fixed(words, start_bit, 1, width)[0])
-
-
 def words_to_bytes(words: np.ndarray, total_bits: int) -> bytes:
     """Serialize the packed stream to the minimal little-endian byte string."""
     nbytes = (total_bits + 7) // 8

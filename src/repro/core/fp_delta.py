@@ -36,11 +36,16 @@ read path, so every stage is one numpy pass):
   markers found, offsets re-derived from the escape cumsum, repeated until
   stable (typically <= 2 rounds; a stable assignment is necessarily the
   unique correct one — token 0's offset is known, and by induction every
-  later offset is determined by the flags before it). Denser streams use the
-  candidate scan: one log-shift AND ladder over the packed words finds every
-  position where ``n`` consecutive ones start (``marker_candidates``), and a
-  short walk over those candidates — O(#escapes), not O(#values) — pins the
-  token-aligned ones as the true markers. Either way, reconstruction is ONE
+  later offset is determined by the flags before it). Denser streams use
+  marker candidates, the positions where ``n`` consecutive ones start. For
+  ``n* >= 15`` every run of that many ones covers a whole ``0xFF`` byte, so
+  one byte scan over all pages of a run finds the runs, and a page with one
+  run per escape takes its markers in closed form (marker ``k`` in run
+  ``k``); other pages hop from marker to marker over the candidates (for
+  ``n* < 15`` a log-shift AND ladder over the words finds them,
+  ``marker_candidates``), and Python runs once per escape. The candidate
+  walk that visits every candidate stays as the reference and the fallback
+  for malformed payloads. Either way, reconstruction is ONE
   segmented cumsum over all reset segments at once: cumsum the inline deltas
   with escapes zeroed, then add a per-segment correction (raw value minus
   the running sum at the escape) spread with ``np.repeat``.
@@ -61,15 +66,17 @@ read path, so every stage is one numpy pass):
 
 from __future__ import annotations
 
+from bisect import bisect_left
 from dataclasses import dataclass
 
 import numpy as np
+
+from repro import obs
 
 from .bitstream import (
     bytes_to_words,
     marker_candidates,
     pack_tokens,
-    read_one,
     unpack_at,
     unpack_fixed,
     words_to_bytes,
@@ -82,7 +89,7 @@ HEADER_BITS = 8
 
 _FIXPOINT_MAX_ROUNDS = 10
 # sparse/dense resolver switch: the fixpoint needs ~E+1 rounds, so beyond a
-# handful of escapes the candidate-scan resolver is strictly better
+# handful of escapes the candidate resolvers are strictly better
 _FIXPOINT_MAX_ESCAPES = 4
 
 
@@ -292,7 +299,7 @@ def _resolve_escapes_fixpoint(
     round locks in at least one more escape, so sparse streams converge in
     about ``n_escapes + 1`` rounds — typically <= 2. Returns
     ``(offsets, flags)`` or None when not converged (denser streams use
-    :func:`_resolve_escapes_scan` instead).
+    the marker candidates instead).
     """
     marker = np.uint64((1 << n) - 1)
     idx = np.arange(n_deltas, dtype=np.int64) * np.int64(n) + np.int64(start_bit)
@@ -313,7 +320,9 @@ def _resolve_escapes_fixpoint(
 def _resolve_escapes_scan(
     words: np.ndarray, start_bit: int, n_deltas: int, n: int, width: int, n_escapes: int
 ) -> tuple[np.ndarray, np.ndarray]:
-    """Escape resolution for any marker density, exact and O(#escapes).
+    """The candidate walk: escape resolution for any marker density, exact.
+    It is the reference of the faster candidate resolvers, and their
+    fallback where a malformed payload leaves them short of markers.
 
     A reset marker is ``n`` consecutive set bits at a token-aligned offset.
     :func:`marker_candidates` finds every bit position where ``n`` ones start
@@ -354,6 +363,163 @@ def _resolve_escapes_scan(
         + np.int64(width) * esc_before
     )
     return offs, flags
+
+
+def _dense(start: int, width: int, n: np.ndarray, n_deltas: np.ndarray,
+           n_escapes: np.ndarray, esc_tok: np.ndarray
+           ) -> tuple[np.ndarray, np.ndarray]:
+    """Token bit offsets and marker flags of pages laid end to end.
+
+    ``n``, ``n_deltas`` and ``n_escapes`` hold each page's token width,
+    token count and escape count; ``esc_tok`` the escaped tokens' indices
+    within their pages, page after page. One cumsum: a token sits ``n`` bits
+    past the one before, ``n + W`` past a marker, and a page's first token
+    at ``start``.
+    """
+    first = np.cumsum(n_deltas) - n_deltas
+    total = int(first[-1] + n_deltas[-1])
+    marks = np.repeat(first, n_escapes) + esc_tok
+    offs = np.repeat(n, n_deltas)  # each token's step from the one before
+    # a page's first token steps back to ``start`` from the page before's
+    # last one, at start + n(D-1) + W*E; where that one is a marker, its
+    # ``+ W`` below lands on this token and the step still comes out right
+    offs[first] = start
+    offs[first[1:]] -= (start + n * (n_deltas - 1) + width * n_escapes)[:-1]
+    after = marks + 1
+    offs[after[after < total]] += width
+    np.cumsum(offs, out=offs)
+    flags = np.zeros(total, dtype=bool)
+    flags[marks] = True
+    return offs, flags
+
+
+def _hop_markers(cands: np.ndarray, start: int, n: int, width: int,
+                 n_escapes: int) -> np.ndarray:
+    """The markers :func:`_resolve_escapes_scan` takes from ``cands``
+    (sorted bit positions), without visiting the stray candidates.
+
+    After a marker at ``c`` the walk takes the first candidate at or past
+    ``c + n + W`` in that position's residue mod ``n``: so each marker
+    depends on the one before alone. One sort by (residue, position) and
+    one ``searchsorted`` give every candidate's successor, and Python runs
+    once per escape.
+    """
+    m = len(cands)
+    if not m:
+        return cands
+    span = max(int(cands[-1]) + n + width, start) + 1  # past every target
+    keys = np.sort(cands % n * span + cands)
+    tgt = keys % span + (n + width)
+    nxt = np.searchsorted(keys, tgt % n * span + tgt).tolist()
+    keys = keys.tolist()
+    hops = []
+    pos = start
+    q = bisect_left(keys, pos % n * span + pos)
+    while q < m and len(hops) < n_escapes:
+        c = keys[q] - pos % n * span
+        if c >= span:  # no candidate left in this residue
+            break
+        hops.append(c)
+        pos = c + n + width
+        q = nxt[q]
+    return np.array(hops, dtype=np.int64)
+
+
+def _resolve_hops(cands, words, start, n_deltas, n, width, n_escapes,
+                  tally) -> tuple[np.ndarray, np.ndarray]:
+    """:func:`_resolve_escapes_scan`'s offsets and flags by candidate hops.
+    Where the hops find fewer markers than the payload length promises (a
+    malformed payload), the walk itself runs."""
+    pos = _hop_markers(cands, start, n, width, n_escapes)
+    esc_tok = (pos - start - width * np.arange(len(pos))) // n
+    if len(esc_tok) == n_escapes and esc_tok[-1] < n_deltas:
+        tally[1] += 1
+        return _dense(start, width, np.array([n]), np.array([n_deltas]),
+                      np.array([n_escapes]), esc_tok)
+    tally[2] += 1
+    return _resolve_escapes_scan(words, start, n_deltas, n, width, n_escapes)
+
+
+# _LOW_ONES[b] / _HIGH_ONES[b]: the set bits at the bottom / top of byte b,
+# which a run of ones from the byte below / above continues into
+_LOW_ONES = np.array([((~b) & (b + 1)).bit_length() - 1 for b in range(256)],
+                     dtype=np.int64)
+_HIGH_ONES = np.array([8 - (255 - b).bit_length() for b in range(256)],
+                      dtype=np.int64)
+# a run of n >= 15 ones covers at least (n - 7) // 8 >= 1 whole 0xFF bytes
+_BYTE_RUN_MIN_BITS = 15
+
+
+def _resolve_closed(buf: np.ndarray, wide: list, start: int, width: int,
+                    tally: list) -> None:
+    """Resolve the escapes of many ``n* >= 15`` pages with one byte scan.
+
+    ``buf`` holds every page's bytes; ``wide`` holds ``[byte offset in buf,
+    byte length of the page's words (spill word included), n, n_deltas,
+    n_escapes, words, plan]`` per page, and each
+    plan's offsets and flags are filled in. The scan finds each maximal run
+    of ones over a whole ``0xFF`` byte of a page's token region and extends
+    it bitwise into the bytes beside it: with ``n >= 15`` these are all the
+    runs a marker can start in.
+
+    A page with exactly ``n_escapes`` such runs, none long enough for two
+    markers (``2n + W`` bits), has marker ``k`` in run ``k``, at its first
+    position ``≡ start + W*k (mod n)``: the walk reaches run ``k`` at that
+    residue, past every candidate of run ``k - 1``, and takes the first
+    aligned one. Each page's assignment is checked (inside its run, at
+    least ``n + W`` bits past the marker before, a token index below
+    ``n_deltas``); a page that fails takes the candidate hops over its runs.
+    """
+    hb = start // 8  # header and first value: whole bytes for W = 32, 64
+    info = np.array([w[:5] for w in wide], dtype=np.int64)
+    base, size, n_p, d_p, e_p = info.T
+    pos = np.flatnonzero(buf == 0xFF)
+    pg = np.searchsorted(base, pos, side="right") - 1
+    pos -= base[pg]  # page-local byte positions
+    inside = (pg >= 0) & (pos >= hb) & (pos < size[pg])
+    if not inside.all():
+        pos, pg = pos[inside], pg[inside]
+    s_at = e_at = pos  # no 0xFF byte: no runs
+    if len(pos):  # runs of consecutive 0xFF bytes, none across two pages
+        cut = np.flatnonzero((pos[1:] != pos[:-1] + 1)
+                             | (pg[1:] != pg[:-1])) + 1
+        s_at = np.concatenate(([0], cut))
+        e_at = np.append(cut - 1, len(pos) - 1)
+    s, e, pg = pos[s_at], pos[e_at] + 1, pg[s_at]
+    a = 8 * s - np.where(s > hb, _HIGH_ONES[buf[base[pg] + s - 1]], 0)
+    b = 8 * e + _LOW_ONES[buf[base[pg] + e]]
+    nn = n_p[pg]
+    keep = b - a >= nn
+    a, b, pg, nn = a[keep], b[keep], pg[keep], nn[keep]
+
+    n_pages = len(wide)
+    cnt = np.bincount(pg, minlength=n_pages)
+    run0 = np.cumsum(cnt) - cnt
+    lag = start + width * (np.arange(len(a)) - run0[pg])
+    m = a + (lag - a) % nn
+    j = (m - lag) // nn
+    ok = (m + nn <= b) & (b - a < 2 * nn + width) & (j < d_p[pg])
+    ok[1:] &= (m[1:] - m[:-1] >= nn[1:] + width) | (pg[1:] != pg[:-1])
+    good = (cnt == e_p) & (np.bincount(pg[~ok], minlength=n_pages) == 0)
+
+    gi = np.flatnonzero(good)
+    if len(gi):  # the good pages' offsets and flags in one pass
+        offs, flags = _dense(start, width, n_p[gi], d_p[gi], e_p[gi],
+                             j[good[pg]])
+        ends = np.cumsum(d_p[gi]).tolist()
+        for q, lo, hi in zip(gi.tolist(), [0] + ends[:-1], ends):
+            wide[q][6][4:6] = offs[lo:hi], flags[lo:hi]
+        tally[0] += len(gi)
+    for q in np.flatnonzero(~good).tolist():
+        _, _, n, n_deltas, n_escapes, words, plan = wide[q]
+        lo, hi = run0[q], run0[q] + cnt[q]
+        ra, rb = a[lo:hi], b[lo:hi]
+        lens = rb - ra - (n - 1)  # candidates of a run: starts a .. b - n
+        ends = np.cumsum(lens)
+        cands = (np.repeat(ra - (ends - lens), lens)
+                 + np.arange(ends[-1] if len(ends) else 0, dtype=np.int64))
+        plan[4:6] = _resolve_hops(cands, words, start, n_deltas, n, width,
+                                  n_escapes, tally)
 
 
 @dataclass(frozen=True)
@@ -403,48 +569,82 @@ def fp_delta_plan(payload, n_values: int, dtype) -> FPDeltaPlan:
     """Parse a payload's header and resolve every escape (Algorithm 2 front
     half). ``payload`` may be any bytes-like buffer (``bytes``,
     ``memoryview``)."""
+    return fp_delta_plan_many([payload], [n_values], dtype)[0]
+
+
+def fp_delta_plan_many(payloads, counts, dtype) -> list[FPDeltaPlan]:
+    """:func:`fp_delta_plan` of each of many pages of one dtype, in order.
+
+    The pages' words are views into one buffer. Each page's path follows
+    from its header and length: no escapes; the fixpoint for a handful;
+    otherwise the marker candidates, and there every ``n* >= 15`` page is
+    resolved with the others in one byte scan (:func:`_resolve_closed`),
+    while ``n* < 15`` pages hop over their :func:`marker_candidates`. The
+    plans equal resolving each page alone with the candidate walk. Counters
+    ``fp_delta.escape_pages.closed``, ``.hop`` and ``.walk`` count the pages
+    each candidate path resolved.
+    """
     dtype = np.dtype(dtype)
     width = dtype.itemsize * 8
     if width not in (32, 64):
         raise TypeError(f"unsupported dtype {dtype}")
-    if n_values == 0:
-        return FPDeltaPlan(dtype, width, 0, 0, 0, np.zeros(1, np.uint64),
-                           _EMPTY_OFFS, _EMPTY_FLAGS, 0)
-
-    words = bytes_to_words(payload)
-    n = read_one(words, 0, HEADER_BITS)
-    cursor = HEADER_BITS
-    if n == 0:  # raw mode: every value raw at W bits, no delta tokens
-        return FPDeltaPlan(dtype, width, 0, n_values, 0, words,
-                           _EMPTY_OFFS, _EMPTY_FLAGS, 0)
-
-    first = read_one(words, cursor, width)
-    cursor += width
-    n_deltas = n_values - 1
-    if n_deltas == 0:
-        return FPDeltaPlan(dtype, width, n, n_values, first, words,
-                           _EMPTY_OFFS, _EMPTY_FLAGS, 0)
-
-    # Exact escape count from the payload length: total bits are
-    # HEADER + W + n*D + W*E plus < 8 bits of byte padding, and W >= 32 > 7,
-    # so the integer division is exact for well-formed payloads.
-    n_escapes = (len(payload) * 8 - cursor - n * n_deltas) // width
-    n_escapes = max(0, min(int(n_escapes), n_deltas))
-
-    if n_escapes == 0:
-        offs = cursor + np.int64(n) * np.arange(n_deltas, dtype=np.int64)
-        flags = np.zeros(n_deltas, dtype=bool)
-    else:
-        resolved = None
+    start = HEADER_BITS + width
+    first_mask = (1 << width) - 1
+    # the words of every page, laid out as bytes_to_words lays out one
+    sizes = [len(p) if c else 0 for p, c in zip(payloads, counts)]
+    n_words = [(size + 7) // 8 + 1 for size in sizes]
+    buf_words = np.zeros(sum(n_words), dtype=np.uint64)
+    buf = buf_words.view(np.uint8)
+    plans = []  # FPDeltaPlan fields per page, from n on
+    wide = []   # [byte offset, byte size, n, n_deltas, n_escapes, words, plan]
+    tally = [0, 0, 0]  # pages resolved closed, by hops, by the walk
+    w0 = 0
+    for payload, n_values, size, nw in zip(payloads, counts, sizes, n_words):
+        words = buf_words[w0 : w0 + nw]
+        if size:
+            buf[8 * w0 : 8 * w0 + size] = np.frombuffer(payload, np.uint8,
+                                                        count=size)
+        w0 += nw
+        n_deltas = n_values - 1
+        head = int.from_bytes(words[:2].tobytes(), "little") if size else 0
+        n = head & 0xFF
+        if n == 0 or n_deltas <= 0:  # empty, raw mode, or a lone value
+            first = (head >> HEADER_BITS) & first_mask if n else 0
+            plans.append([n, n_values, first, words, _EMPTY_OFFS,
+                          _EMPTY_FLAGS, 0])
+            continue
+        # Exact escape count from the payload length: total bits are
+        # HEADER + W + n*D + W*E plus < 8 bits of byte padding, and
+        # W >= 32 > 7, so the integer division is exact for well-formed
+        # payloads.
+        n_escapes = (size * 8 - start - n * n_deltas) // width
+        n_escapes = max(0, min(n_escapes, n_deltas))
+        plan = [n, n_values, (head >> HEADER_BITS) & first_mask, words,
+                None, None, n_escapes]
+        plans.append(plan)
+        if n_escapes == 0:
+            plan[4] = start + np.arange(0, n * n_deltas, n, dtype=np.int64)
+            plan[5] = np.zeros(n_deltas, dtype=bool)
+            continue
         if n_escapes <= _FIXPOINT_MAX_ESCAPES:
             resolved = _resolve_escapes_fixpoint(
-                words, cursor, n_deltas, n, width, n_escapes)
-        if resolved is None:
-            resolved = _resolve_escapes_scan(
-                words, cursor, n_deltas, n, width, n_escapes)
-        offs, flags = resolved
-    return FPDeltaPlan(dtype, width, n, n_values, first, words,
-                       offs, flags, n_escapes)
+                words, start, n_deltas, n, width, n_escapes)
+            if resolved is not None:
+                plan[4:6] = resolved
+                continue
+        if n >= _BYTE_RUN_MIN_BITS:
+            wide.append([8 * (w0 - nw), 8 * nw, n, n_deltas, n_escapes,
+                         words, plan])
+            continue
+        plan[4:6] = _resolve_hops(marker_candidates(words, n), words, start,
+                                 n_deltas, n, width, n_escapes, tally)
+    if wide:
+        _resolve_closed(buf, wide, start, width, tally)
+    if obs.enabled():
+        for name, k in zip(("closed", "hop", "walk"), tally):
+            if k:
+                obs.count(f"fp_delta.escape_pages.{name}", k)
+    return [FPDeltaPlan(dtype, width, *plan) for plan in plans]
 
 
 def fp_delta_execute(plan: FPDeltaPlan, out: np.ndarray | None = None) -> np.ndarray:

@@ -30,6 +30,7 @@ from .fp_delta import (
     fp_delta_encode,
     fp_delta_encode_pages,
     fp_delta_plan,
+    fp_delta_plan_many,
 )
 
 ENC_FP_DELTA = "fp_delta"
@@ -186,8 +187,10 @@ def page_plan(buf, meta: PageMeta, dtype, codec: str) -> FPDeltaPlan:
     return fp_delta_plan(decompress(buf, codec), meta.count, dtype)
 
 
-def page_stream_plan(buf, meta: PageMeta, dtype, codec: str) -> FPDeltaPlan:
-    """Like :func:`page_plan`, but accepts **every** coordinate encoding.
+def page_stream_plans(pages, dtype, codec: str) -> list[FPDeltaPlan]:
+    """Stream plans of ``(buf, meta)`` coordinate pages of **every**
+    encoding, in order: the FP-delta pages planned together
+    (:func:`~repro.core.fp_delta.fp_delta_plan_many`).
 
     Raw pages are mapped onto a *synthetic raw-mode plan* — a zero byte
     (standing in for the fp_delta ``n* = 0`` header) prepended to the stored
@@ -198,23 +201,34 @@ def page_stream_plan(buf, meta: PageMeta, dtype, codec: str) -> FPDeltaPlan:
     individual pages were encoded. Bit-identical to ``np.frombuffer`` on the
     payload (little-endian word math either way).
     """
-    if meta.encoding == ENC_FP_DELTA:
-        return page_plan(buf, meta, dtype, codec)
-    if meta.encoding != ENC_RAW:
-        raise ValueError(f"unknown encoding {meta.encoding!r}")
     dtype = np.dtype(dtype)
     width = dtype.itemsize * 8
     if width not in (32, 64):
         raise TypeError(f"unsupported dtype {dtype}")
-    payload = decompress(buf, codec)
-    if meta.count == 0:
-        return FPDeltaPlan(dtype, width, 0, 0, 0, np.zeros(1, np.uint64),
-                           _EMPTY_OFFS, _EMPTY_FLAGS, 0)
-    shifted = bytearray(1 + len(payload))
-    shifted[1:] = payload
-    assert HEADER_BITS == 8, "synthetic raw plan assumes a one-byte header"
-    return FPDeltaPlan(dtype, width, 0, meta.count, 0, bytes_to_words(shifted),
-                       _EMPTY_OFFS, _EMPTY_FLAGS, 0)
+    plans: list = [None] * len(pages)
+    at, payloads, counts = [], [], []
+    for i, (buf, meta) in enumerate(pages):
+        if meta.encoding == ENC_FP_DELTA:
+            at.append(i)
+            payloads.append(decompress(buf, codec))
+            counts.append(meta.count)
+            continue
+        if meta.encoding != ENC_RAW:
+            raise ValueError(f"unknown encoding {meta.encoding!r}")
+        payload = decompress(buf, codec)
+        if meta.count == 0:
+            plans[i] = FPDeltaPlan(dtype, width, 0, 0, 0, np.zeros(1, np.uint64),
+                                   _EMPTY_OFFS, _EMPTY_FLAGS, 0)
+            continue
+        shifted = bytearray(1 + len(payload))
+        shifted[1:] = payload
+        assert HEADER_BITS == 8, "synthetic raw plan assumes a one-byte header"
+        plans[i] = FPDeltaPlan(dtype, width, 0, meta.count, 0,
+                               bytes_to_words(shifted), _EMPTY_OFFS,
+                               _EMPTY_FLAGS, 0)
+    for i, plan in zip(at, fp_delta_plan_many(payloads, counts, dtype)):
+        plans[i] = plan
+    return plans
 
 
 def encode_pages(

@@ -61,7 +61,7 @@ over IEEE-754 order keys — uint32 limb math, so float64 refines without
 (``repro.kernels.fp_delta.decode_refine_stream``). Pruned records **never
 materialize on the host**: only the record mask and the surviving
 coordinates cross back (raw-encoded pages join the launch through a
-synthetic raw-mode plan, see ``pages.page_stream_plan``). The surviving
+synthetic raw-mode plan, see ``pages.page_stream_plans``). The surviving
 record set is bit-identical to the host refine. ``keep_on_device=True``
 additionally leaves the surviving coordinates on the accelerator, returning
 :class:`~repro.core.columnar.DeviceCoords` columns for zero-copy handoff
@@ -112,7 +112,7 @@ from .pages import (
     decode_page,
     decompress,
     page_plan,
-    page_stream_plan,
+    page_stream_plans,
 )
 from .rle import decode_levels, rle_decode
 from .writer import MAGIC, MAGIC_V2, permute_records
@@ -576,11 +576,11 @@ class SpatialParquetReader:
     def _stream_plans(self, pages, plans: list) -> None:
         """Append the x and y stream plan of each verified page to
         ``plans`` (one ``rg.stream_plan`` span)."""
-        dtype = self.coord_dtype
         with obs.span("rg.stream_plan", cat="plan", pages=len(pages)):
-            for meta_x, blob_x, meta_y, blob_y in pages:
-                plans.append(page_stream_plan(blob_x, meta_x, dtype, self.codec))
-                plans.append(page_stream_plan(blob_y, meta_y, dtype, self.codec))
+            plans += page_stream_plans(
+                [pair for meta_x, blob_x, meta_y, blob_y in pages
+                 for pair in ((blob_x, meta_x), (blob_y, meta_y))],
+                self.coord_dtype, self.codec)
 
     def _iter_sources(self, items, coalesce: bool):
         """Yield ``(item, src)`` per hit row group, double-buffering reads.

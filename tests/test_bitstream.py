@@ -11,7 +11,6 @@ from repro.core.bitstream import (
     bytes_to_words,
     marker_candidates,
     pack_tokens,
-    read_one,
     unpack_at,
     unpack_fixed,
     width_mask,
@@ -42,7 +41,7 @@ def _check_pack_then_sequential_read(tokens):
     assert total == int(widths.sum())
     off = 0
     for v, w in tokens:
-        got = read_one(words, off, w)
+        got = int(unpack_fixed(words, off, 1, w)[0])
         assert got == (v & int(width_mask(w))), (v, w)
         off += w
 
@@ -63,7 +62,7 @@ def _check_bytes_serialization_roundtrip(tokens):
     words2 = bytes_to_words(buf)
     off = 0
     for v, w in tokens:
-        assert read_one(words2, off, w) == (v & int(width_mask(w)))
+        assert int(unpack_fixed(words2, off, 1, w)[0]) == (v & int(width_mask(w)))
         off += w
 
 
@@ -110,8 +109,8 @@ def test_mixed_stream_alignment():
     vals = [5, 0xDEADBEEFCAFEF00D] + list(range(100))
     widths = [8, 64] + [7] * 100
     words, total = pack_tokens(np.array(vals, np.uint64), np.array(widths, np.int64))
-    assert read_one(words, 0, 8) == 5
-    assert read_one(words, 8, 64) == 0xDEADBEEFCAFEF00D
+    assert int(unpack_fixed(words, 0, 1, 8)[0]) == 5
+    assert int(unpack_fixed(words, 8, 1, 64)[0]) == 0xDEADBEEFCAFEF00D
     got = unpack_fixed(words, 72, 100, 7)
     assert np.array_equal(got, np.arange(100, dtype=np.uint64))
 

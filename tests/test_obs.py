@@ -613,3 +613,51 @@ def test_obs_imports_and_traces_without_jax():
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=120)
     assert out.returncode == 0, out.stderr
+
+
+def test_escape_path_counters(tmp_path, bench_data):
+    """The planner counts the pages each escape resolver took: a PT-like
+    coordinate page run resolves in closed form without the walk, and an
+    ``n* = 5`` attribute page (eB's ``count``) hops; nothing is counted
+    with telemetry off."""
+    from repro.core.fp_delta import fp_delta_plan
+    from repro.core.pages import PageMeta, encode_page, page_stream_plans
+
+    cfg, data = bench_data("pt_taxi", 400, 2**31 + 3)
+    root = str(tmp_path / "pt")
+    write_dataset(root, columns=from_ragged(
+        data["types"], data["coords"], data["part_sizes"],
+        data["parts_per_record"]), n_shards=1, sort=cfg["sort"],
+        page_values=int(cfg["page_values"]))
+    sc = SpatialDatasetScanner(root)
+    with sc.open_shard(0) as r:
+        with open(r.path, "rb") as f:
+            raw = f.read()
+        (rg,) = r.footer["row_groups"]
+        run = []
+        for dx, dy in zip(rg["x_pages"], rg["y_pages"]):
+            for m in (PageMeta.from_dict(dx), PageMeta.from_dict(dy)):
+                run.append((raw[m.offset : m.offset + m.nbytes], m))
+        codec = r.codec
+    assert len(run) >= 4
+    _, eb = bench_data("eb_points", 8192, 2**31 + 5)
+    count_page, st = encode_page(eb["extras"]["count"], "fp_delta", "none")
+    assert st["n_bits"] == 5 and st["n_resets"] > 4
+
+    page_stream_plans(run, np.float64, codec)
+    fp_delta_plan(count_page, 8192, np.int32)
+    assert not obs.snapshot()["counters"]
+    obs.enable()
+    try:
+        plans = page_stream_plans(run, np.float64, codec)
+        c = dict(obs.snapshot()["counters"])
+        fp_delta_plan(count_page, 8192, np.int32)
+        c_all = obs.snapshot()["counters"]
+    finally:
+        obs.disable()
+    escaped = sum(p.n_escapes > 4 for p in plans)
+    assert c.get("fp_delta.escape_pages.closed", 0) == escaped > 0
+    assert "fp_delta.escape_pages.walk" not in c
+    assert c_all["fp_delta.escape_pages.hop"] == \
+        c.get("fp_delta.escape_pages.hop", 0) + 1
+    assert "fp_delta.escape_pages.walk" not in c_all
