@@ -183,6 +183,17 @@ class GeometryColumns:
         return self.types, coords, part_sizes, parts_per_subgeom, subgeoms_per_record
 
 
+def ragged_ranges(starts: np.ndarray, counts: np.ndarray) -> np.ndarray:
+    """Concatenate ``arange(s, s + c)`` for each (start, count) pair."""
+    counts = np.asarray(counts, np.int64)
+    total = int(counts.sum())
+    if total == 0:
+        return np.zeros(0, np.int64)
+    rep_start = np.repeat(np.asarray(starts, np.int64), counts)
+    excl = np.cumsum(counts) - counts
+    return rep_start + (np.arange(total, dtype=np.int64) - np.repeat(excl, counts))
+
+
 def from_ragged(
     types: np.ndarray,
     coords: np.ndarray,

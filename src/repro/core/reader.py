@@ -102,6 +102,7 @@ from repro.io.checksum import ChecksumError, checksum_fn, crc32c
 from repro.io.source import LocalFileSource
 
 from .columnar import DeviceCoords, GeometryColumns, assemble
+from .exact import edge_partners, narrow as exact_narrow
 from .filters import Predicate, canonical_bbox, validate_predicate
 from .fp_delta import fp_delta_execute
 from .geometry import Geometry
@@ -314,10 +315,7 @@ class _RowGroupLevels:
     def record_value_counts(self) -> np.ndarray:
         """Values per record across the whole row group (pages are
         record-aligned, so hit runs slice out of this contiguously)."""
-        d64 = self.defn.astype(np.int64)
-        value_idx = np.cumsum(d64) - d64
-        total = int(value_idx[-1] + d64[-1]) if len(d64) else 0
-        return np.diff(np.append(value_idx[self.slot_starts], total))
+        return np.add.reduceat(self.defn, self.slot_starts, dtype=np.int64)
 
 
 @dataclass
@@ -639,6 +637,7 @@ class SpatialParquetReader:
         *,
         keep_on_device: bool = False,
         filter: Predicate | None = None,
+        exact: bool = False,
     ) -> tuple[GeometryColumns | None, dict[str, np.ndarray], ReadStats]:
         """Decode records whose *page* bbox intersects ``bbox``.
 
@@ -664,6 +663,14 @@ class SpatialParquetReader:
         columns always decode on the host). ``"cpu"`` is the default and the
         oracle.
 
+        ``exact=True`` (with ``refine=True``) answers by geometry instead of
+        MBR: a record is returned iff its closed point set meets the closed
+        box (polygons by the even–odd rule; see :mod:`repro.core.exact`).
+        The MBR refine still runs first; on the device path the records it
+        keeps go through the compare-only stage of
+        :mod:`repro.kernels.exact`, and only the ones it cannot decide
+        reach the host, for exact orientation signs.
+
         With telemetry on (``repro.obs.enable()``) the call is wrapped in a
         ``scan.file`` span with per-row-group fetch/plan/decode/launch/
         transfer child spans, and on return folds its ``ReadStats`` plus the
@@ -671,17 +678,19 @@ class SpatialParquetReader:
         bytes-pruned-per-level) into the metrics registry. Disabled, the
         path is allocation- and result-identical to the uninstrumented one.
         """
+        if exact and not refine:
+            raise ValueError("exact=True requires refine=True")
         if not obs.enabled():
             return self._read_columnar_impl(
                 bbox, columns, refine, coalesce, device,
-                keep_on_device=keep_on_device, filter=filter)
+                keep_on_device=keep_on_device, filter=filter, exact=exact)
         t0 = time.perf_counter()
         c0 = time.process_time()
         with obs.span("scan.file", path=self.path, device=device,
                       refine=bool(refine), filtered=filter is not None):
             out = self._read_columnar_impl(
                 bbox, columns, refine, coalesce, device,
-                keep_on_device=keep_on_device, filter=filter)
+                keep_on_device=keep_on_device, filter=filter, exact=exact)
         wall = time.perf_counter() - t0
         cpu = time.process_time() - c0
         stats = out[2]
@@ -697,9 +706,12 @@ class SpatialParquetReader:
         return out
 
     def _read_columnar_impl(self, bbox, columns, refine, coalesce, device,
-                            *, keep_on_device, filter=None):
+                            *, keep_on_device, filter=None, exact=False):
         if device not in ("cpu", "jax"):
             raise ValueError(f"device must be 'cpu' or 'jax', got {device!r}")
+        if exact and self.coord_dtype.kind != "f":
+            raise ValueError("exact=True requires float coordinates")
+        exact = exact and refine and bbox is not None
         use_device = device == "jax"
         if keep_on_device and not use_device:
             raise ValueError("keep_on_device=True requires device='jax'")
@@ -754,7 +766,7 @@ class SpatialParquetReader:
         if fused:
             out = self._read_columnar_fused(
                 bbox, refine, coalesce, keep_on_device, read_extra,
-                items, stats, hit, filter=filter)
+                items, stats, hit, filter=filter, exact=exact)
             if filter is not None:
                 geo_f, extras_f, stats_f = out
                 out = (geo_f, {k: extras_f[k] for k in want_extra}, stats_f)
@@ -867,6 +879,12 @@ class SpatialParquetReader:
             if we and obs.enabled():
                 obs.observe("filter.selectivity", float(attr.sum()) / we)
             keep_mask = attr if keep_mask is None else keep_mask & attr
+        if exact and geo is not None:
+            # exact implies refine with a bbox: counts is refine.host's
+            with obs.span("refine.exact", cat="refine"):
+                off, poly = edge_partners(geo.rep, geo.defn, geo.types)
+                keep_mask = exact_narrow(geo.x, geo.y, counts, off, poly,
+                                         keep_mask, bbox)
         if keep_mask is not None:
             if geo is not None:
                 geo = permute_records(geo, np.flatnonzero(keep_mask))
@@ -892,7 +910,8 @@ class SpatialParquetReader:
 
     # ------------------------------------------------------ fused device scan
     def _read_columnar_fused(self, bbox, refine, coalesce, keep_on_device,
-                             want_extra, items, stats, hit, filter=None):
+                             want_extra, items, stats, hit, filter=None,
+                             exact=False):
         """Decode → per-record bbox refine → compact, all device-resident.
 
         Per row group: levels decode on the host (they drive segmentation),
@@ -907,7 +926,12 @@ class SpatialParquetReader:
         computes ``bbox ∧ attrs`` in one pass and survivor compaction (the
         gather back to the host) already excludes records the predicate
         rejects.
+
+        With ``exact`` the records the refine keeps go on to the exact
+        stage (:func:`repro.kernels.exact.exact_narrow`) inside the same
+        ``rg.launch``; ``build_refine_aux`` then carries their edges.
         """
+        from repro.kernels.exact import exact_narrow as exact_narrow_device
         from repro.kernels.fp_delta import (
             build_page_stream,
             build_refine_aux,
@@ -946,6 +970,7 @@ class SpatialParquetReader:
                 lv = self._decode_rg_levels(src, rg, stats)
                 rec_vcounts_rg = lv.record_value_counts()
                 we0 = we  # this row group's record span in the extra columns
+                lv0 = len(rep_parts)  # this row group's first level part
 
                 plans: list = []            # x,y plan per page, stream order
                 pairs: list[tuple[int, int]] = []   # local record range per pair
@@ -975,6 +1000,12 @@ class SpatialParquetReader:
                     plan_span.add(pages=len(pairs))
                 rec_vcounts = (np.concatenate(vc_parts) if vc_parts
                                else np.zeros(0, np.int64))
+                if exact:
+                    # edges of the read records only, from their levels
+                    off_read, poly_read = edge_partners(
+                        *(np.concatenate(parts[lv0:]) for parts in
+                          (rep_parts, defn_parts, types_parts)))
+                    vbound_read = np.concatenate([[0], np.cumsum(rec_vcounts)])
                 # host-evaluated attribute mask for this row group's read
                 # records (aligned with rec_vcounts / the chunk record ranges)
                 attr_rg = None
@@ -986,6 +1017,10 @@ class SpatialParquetReader:
                 for kind, cplans, cpairs, (rl, rh) in chunk_plan_pairs(plans, pairs):
                     vc = rec_vcounts[rl:rh]
                     attr_c = attr_rg[rl:rh] if attr_rg is not None else None
+                    edges_c = None
+                    if exact:
+                        v0, v1 = int(vbound_read[rl]), int(vbound_read[rh])
+                        edges_c = (off_read[v0:v1], poly_read[v0:v1])
                     if kind == "host":
                         # a single page too large for any launch: decode this
                         # pair on the host (same bits via fp_delta_execute;
@@ -998,6 +1033,9 @@ class SpatialParquetReader:
                                       if do_refine else np.ones(len(vc), bool))
                             if attr_c is not None:
                                 keep_c = keep_c & attr_c
+                            if exact:
+                                keep_c = exact_narrow(x_v, y_v, vc, *edges_c,
+                                                      keep_c, bbox)
                             starts = np.cumsum(vc) - vc
                             iv = ragged_ranges(starts[keep_c], vc[keep_c])
                             xs, ys = x_v[iv], y_v[iv]
@@ -1017,7 +1055,7 @@ class SpatialParquetReader:
                             stream = build_page_stream(cplans)
                             aux = build_refine_aux(
                                 stream, [(a - rl, b - rl) for a, b in cpairs],
-                                vc)
+                                vc, edges=edges_c)
                             if attr_c is not None and do_refine:
                                 # the device record mask is valid ∧ bbox;
                                 # AND-ing the attribute mask into a fresh copy
@@ -1029,6 +1067,9 @@ class SpatialParquetReader:
                         if do_refine:
                             res = decode_refine_stream(stream, aux, bbox)
                             keep_c, lo_d, hi_d = res.keep, res.lo, res.hi
+                            if exact:
+                                keep_c = exact_narrow_device(
+                                    lo_d, hi_d, aux, keep_c, bbox, width, dtype)
                         else:
                             lo_d, hi_d = decode_stream_device(stream)
                             keep_c = (attr_c.copy() if attr_c is not None

@@ -78,5 +78,13 @@ def decode_levels(buf: bytes) -> np.ndarray:
     if mode == MODE_RLE:
         return rle_decode(body)
     width, count = struct.unpack_from("<BI", body, 0)
+    if 8 % width == 0:
+        # widths that tile a byte (levels are 1 or 2 bits): shift each
+        # byte by every slot offset at once, LSB-first as pack_tokens wrote
+        packed = np.frombuffer(body, np.uint8, count=(count * width + 7) // 8,
+                               offset=5)
+        shifts = np.arange(0, 8, width, dtype=np.uint8)
+        return ((packed[:, None] >> shifts)
+                & np.uint8((1 << width) - 1)).reshape(-1)[:count]
     words = bytes_to_words(body[5:])
     return unpack_fixed(words, 0, count, width).astype(np.uint8)

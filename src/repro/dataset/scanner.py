@@ -191,7 +191,7 @@ class SpatialDatasetScanner:
         return self._open_shard(shard_path(self.root, self.manifest.shards[shard_i]))
 
     def _read_shard_once(self, path: str, bbox, columns, refine, coalesce,
-                         device, keep_on_device, filter):
+                         device, keep_on_device, filter, exact):
         src = self._open_source(path)
         try:
             with SpatialParquetReader(
@@ -202,6 +202,7 @@ class SpatialDatasetScanner:
                     bbox=bbox, columns=columns, refine=refine,
                     coalesce=coalesce, device=device,
                     keep_on_device=keep_on_device, filter=filter,
+                    exact=exact,
                 )
         except Exception as exc:
             # a failed attempt still did real I/O (and maybe retried,
@@ -213,7 +214,7 @@ class SpatialDatasetScanner:
 
     def _read_shard(self, manifest: DatasetManifest, shard_i: int, bbox,
                     columns, refine, coalesce, device, keep_on_device,
-                    filter):
+                    filter, exact=False):
         """Read one shard under the scanner's error policy.
 
         ``manifest`` is the scan's pinned snapshot — passed explicitly so a
@@ -238,7 +239,7 @@ class SpatialDatasetScanner:
                 try:
                     res = self._read_shard_once(
                         path, bbox, columns, refine, coalesce, device,
-                        keep_on_device, filter)
+                        keep_on_device, filter, exact)
                     return res, attempt, None, failed
                 except SHARD_ERRORS as exc:
                     last = exc
@@ -266,6 +267,7 @@ class SpatialDatasetScanner:
         *,
         keep_on_device: bool = False,
         filter=None,
+        exact: bool = False,
     ) -> tuple[GeometryColumns | None, dict[str, np.ndarray], ReadStats]:
         """Dataset-wide ``read_columnar``: shard pruning + parallel fan-out.
 
@@ -287,6 +289,11 @@ class SpatialDatasetScanner:
         predicate at page and record granularity, and results equal a full
         scan masked by the predicate row-by-row.
 
+        ``exact=True`` (only with ``refine=True``) answers by geometry: a
+        record is returned iff its closed point set meets the closed box,
+        as :meth:`SpatialParquetReader.read_columnar` describes. The
+        default answers by MBR.
+
         With telemetry on (``repro.obs.enable()``) the query runs inside a
         ``scan.dataset`` span with one ``shard`` child span per surviving
         shard (worker threads inherit the span context), and on return
@@ -294,9 +301,11 @@ class SpatialDatasetScanner:
         ``scan.host_cpu_s_per_gb`` gauge and the shard-level pruned-bytes
         counter. Telemetry off is the plain, allocation-identical path.
         """
+        if exact and not refine:
+            raise ValueError("exact=True requires refine=True")
         if not obs.enabled():
             return self._scan_impl(bbox, columns, refine, parallel, coalesce,
-                                   device, keep_on_device, filter)
+                                   device, keep_on_device, filter, exact)
         t0 = time.perf_counter()
         c0 = time.process_time()
         with obs.span("scan.dataset", root=self.root, device=device,
@@ -304,7 +313,7 @@ class SpatialDatasetScanner:
                       filtered=filter is not None) as sp:
             geo, extras, stats = self._scan_impl(
                 bbox, columns, refine, parallel, coalesce, device,
-                keep_on_device, filter)
+                keep_on_device, filter, exact)
             sp.add(shards_read=stats.shards_read,
                    records=stats.records_returned)
         wall = time.perf_counter() - t0
@@ -317,7 +326,7 @@ class SpatialDatasetScanner:
         return geo, extras, stats
 
     def _scan_impl(self, bbox, columns, refine, parallel, coalesce, device,
-                   keep_on_device, filter=None):
+                   keep_on_device, filter=None, exact=False):
         # every scan holds a pin on its generation for its whole duration:
         # a compaction commit + GC racing the scan cannot delete the shard
         # files this scan is reading. Unpinned scanners pin the *current
@@ -334,13 +343,14 @@ class SpatialDatasetScanner:
             manifest, index = self._view(generation)
             return self._scan_pinned(
                 manifest, index, bbox, columns, refine, parallel, coalesce,
-                device, keep_on_device, filter)
+                device, keep_on_device, filter, exact)
         finally:
             if release:
                 pin.release()
 
     def _scan_pinned(self, manifest, index, bbox, columns, refine, parallel,
-                     coalesce, device, keep_on_device, filter=None):
+                     coalesce, device, keep_on_device, filter=None,
+                     exact=False):
         hit = index.query(bbox, filter=filter)
         hit_set = set(int(i) for i in hit)
         stats = ReadStats(shards_total=len(index), shards_read=len(hit))
@@ -365,7 +375,7 @@ class SpatialDatasetScanner:
                 futures = [
                     obs.submit(pool, self._read_shard, manifest, int(i), bbox,
                                columns, refine, coalesce, device,
-                               keep_on_device, filter)
+                               keep_on_device, filter, exact)
                     for i in hit
                 ]
                 # gather in submission (manifest) order: deterministic output
@@ -373,7 +383,8 @@ class SpatialDatasetScanner:
         else:
             outcomes = [
                 self._read_shard(manifest, int(i), bbox, columns, refine,
-                                 coalesce, device, keep_on_device, filter)
+                                 coalesce, device, keep_on_device, filter,
+                                 exact)
                 for i in hit
             ]
 
@@ -420,14 +431,16 @@ class SpatialDatasetScanner:
         *,
         keep_on_device: bool = False,
         filter=None,
+        exact: bool = False,
     ):
         """Drop-in for :meth:`SpatialParquetReader.read_columnar` (same
         positional order; the extra ``parallel`` knob comes last,
-        ``keep_on_device``/``filter`` are keyword-only everywhere)."""
+        ``keep_on_device``/``filter``/``exact`` are keyword-only
+        everywhere)."""
         return self.scan(
             bbox=bbox, columns=columns, refine=refine,
             parallel=parallel, coalesce=coalesce, device=device,
-            keep_on_device=keep_on_device, filter=filter,
+            keep_on_device=keep_on_device, filter=filter, exact=exact,
         )
 
     def read(self, bbox=None, refine: bool = False) -> tuple[list[Geometry], ReadStats]:

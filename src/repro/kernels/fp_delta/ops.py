@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.core.columnar import DeviceCoords
+from repro.core.columnar import DeviceCoords, ragged_ranges
 from repro.core.fp_delta import HEADER_BITS, FPDeltaPlan, fp_delta_execute
 
 from .. import DeviceCompileError, default_interpret
@@ -498,7 +498,10 @@ class RefineAux:
     anchor-padding rule of the decode kernel); ``end_pos[r] = (x_end,
     y_end)`` is where the inclusive segmented scan holds record ``r``'s
     reduction. ``x_start``/``y_start``/``counts`` are the slice geometry the
-    host uses to build survivor gather indices.
+    host uses to build survivor gather indices. ``edge_off``/``edge_poly``
+    (exact scans only) are the records' ring and line-string edges over
+    their values in record order, from the rep levels
+    (:func:`repro.core.exact.edge_partners`).
     """
 
     seg_flag: np.ndarray   # (n_blocks, STREAM_BLOCK) int32, 1 at slice starts
@@ -508,17 +511,23 @@ class RefineAux:
     x_start: np.ndarray    # (n_records,) int64 stream offset of x slice
     y_start: np.ndarray    # (n_records,) int64
     counts: np.ndarray     # (n_records,) int64 values per record (per axis)
+    edge_off: np.ndarray | None = None    # (sum(counts),) int32
+    edge_poly: np.ndarray | None = None   # (sum(counts),) bool
 
 
-def build_refine_aux(stream: PageStream, pairs, rec_vcounts) -> RefineAux:
+def build_refine_aux(stream: PageStream, pairs, rec_vcounts,
+                     edges=None) -> RefineAux:
     """Segment a stream built from interleaved x,y page pairs by record.
 
     ``pairs[i] = (r0, r1)``: the record range covered by the i-th x/y page
     pair (``stream.counts[2i]``/``[2i+1]`` are its value counts); records are
     indexed locally and contiguously across pairs. ``rec_vcounts[r]`` is the
-    per-axis value count of record ``r``.
+    per-axis value count of record ``r``. ``edges = (off, poly)``, for exact
+    scans, carries the records' edges from their rep levels.
     """
     counts = np.ascontiguousarray(rec_vcounts, dtype=np.int64)
+    if edges is not None and any(len(a) != counts.sum() for a in edges):
+        raise ValueError("edges must hold one entry per record value")
     n_rec = len(counts)
     total = stream.n_values
     n_pad_vals = stream.tok_off.size
@@ -547,7 +556,8 @@ def build_refine_aux(stream: PageStream, pairs, rec_vcounts) -> RefineAux:
     valid = np.zeros(n_rec_pad, bool)
     valid[:n_rec] = counts > 0
     return RefineAux(flag.reshape(stream.tok_off.shape), end, valid, n_rec,
-                     x_start, y_start, counts)
+                     x_start, y_start, counts,
+                     *(edges if edges is not None else ()))
 
 
 @functools.lru_cache(maxsize=None)
@@ -803,17 +813,6 @@ def refine_minmax_multi(minmax, valid, qkeys, qvalid, *, width: int,
 _take_limbs_jit = jax.jit(
     lambda lo, hi, idx: (jnp.take(lo, idx, mode="clip"),
                          jnp.take(hi, idx, mode="clip")))
-
-
-def ragged_ranges(starts: np.ndarray, counts: np.ndarray) -> np.ndarray:
-    """Concatenate ``arange(s, s + c)`` for each (start, count) pair."""
-    counts = np.asarray(counts, np.int64)
-    total = int(counts.sum())
-    if total == 0:
-        return np.zeros(0, np.int64)
-    rep_start = np.repeat(np.asarray(starts, np.int64), counts)
-    excl = np.cumsum(counts) - counts
-    return rep_start + (np.arange(total, dtype=np.int64) - np.repeat(excl, counts))
 
 
 def gather_stream_values(lo, hi, idx: np.ndarray, width: int, dtype,

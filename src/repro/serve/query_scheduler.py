@@ -311,7 +311,16 @@ class SpatialQueryServer:
         return r
 
     # ------------------------------------------------------------------ API
-    def submit(self, bbox=None, columns=None, filter=None) -> SpatialQuery:
+    def submit(self, bbox=None, columns=None, filter=None, *,
+               exact: bool = False) -> SpatialQuery:
+        """Queue one query; it answers by MBR, as ``scan(bbox, refine=True,
+        filter=filter)``. ``exact=True`` is refused: exact geometry tests
+        run through ``SpatialDatasetScanner.scan(..., exact=True)``."""
+        if exact:
+            raise ValueError(
+                "SpatialQueryServer answers by MBR only; exact=True is not "
+                "supported here: use SpatialDatasetScanner.scan(bbox, "
+                "refine=True, exact=True)")
         if filter is not None:
             from repro.core.filters import validate_predicate
 

@@ -130,6 +130,26 @@ def test_levels_roundtrip(rng):
         assert np.array_equal(decode_levels(encode_levels(v)), v)
 
 
+@pytest.mark.parametrize("top", [1, 3, 7, 15, 200])
+@pytest.mark.parametrize("n", [1, 7, 8, 9, 65, 4099])
+def test_packed_levels_match_bit_reader(rng, top, n):
+    """Every packed width (byte-tiling 1/2/4/8 bits or not) decodes to the
+    values the general bit reader finds in the same stream."""
+    from repro.core.bitstream import bytes_to_words, unpack_fixed
+    from repro.core.rle import MODE_PACKED
+
+    v = rng.integers(0, top + 1, n).astype(np.uint8)
+    v[0] = top  # pins the width at top's bit length
+    v[1::2] = np.arange(len(v[1::2])) % (top + 1)  # defeats the RLE choice
+    buf = encode_levels(v)
+    got = decode_levels(buf)
+    assert got.dtype == np.uint8 and np.array_equal(got, v)
+    if buf[0] == MODE_PACKED:
+        width, count = buf[1], len(v)
+        want = unpack_fixed(bytes_to_words(buf[6:]), 0, count, width)
+        assert np.array_equal(got, want.astype(np.uint8))
+
+
 def test_hilbert_locality(rng):
     # consecutive hilbert cells are spatial neighbors: d(k, k+1) == 1 step
     order = 6

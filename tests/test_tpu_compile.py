@@ -104,3 +104,20 @@ def test_fused_refine_chain_compiles(one_chip, chain, width):
         (qkeys, jnp.uint32),
     ]
     _compile(fn, shapes, one_chip)
+
+
+@pytest.mark.parametrize("width", [32, 64])
+def test_exact_stage_compiles(one_chip, width):
+    """The exact stage is plain XLA (gathers, compares, cumsums), under its
+    own module name so the trace keeps it apart from the refine chain."""
+    from repro.kernels import exact
+
+    n_vals = 1 << 18   # the gathered values of a launch's kept records
+    stream = ((N_BLOCKS * STREAM_BLOCK,), jnp.uint32)
+    vals = ((n_vals,), jnp.int32)
+    shapes = [stream, stream, vals, vals, vals, ((n_vals,), jnp.bool_),
+              vals, vals, ((5, 2), jnp.uint32)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    lowered = exact._exact_jit(width).lower(*args)
+    assert "jit_exact_fn" in lowered.as_text()
+    lowered.compile()
