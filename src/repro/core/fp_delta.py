@@ -62,6 +62,8 @@ read path, so every stage is one numpy pass):
   second half on the accelerator (Pallas page-stream decode), so the two
   back ends can never disagree about the format. :func:`fp_delta_decode`
   is plan + host execute and stays the oracle.
+  :func:`fp_delta_execute_many` finishes many plans of one column at once
+  (the reader's attribute pages), bit-identical to executing each.
 """
 
 from __future__ import annotations
@@ -79,6 +81,7 @@ from .bitstream import (
     pack_tokens,
     unpack_at,
     unpack_fixed,
+    width_mask,
     words_to_bytes,
 )
 
@@ -425,19 +428,39 @@ def _hop_markers(cands: np.ndarray, start: int, n: int, width: int,
     return np.array(hops, dtype=np.int64)
 
 
-def _resolve_hops(cands, words, start, n_deltas, n, width, n_escapes,
-                  tally) -> tuple[np.ndarray, np.ndarray]:
-    """:func:`_resolve_escapes_scan`'s offsets and flags by candidate hops.
-    Where the hops find fewer markers than the payload length promises (a
-    malformed payload), the walk itself runs."""
+def _hop_tokens(cands, start, n_deltas, n, width, n_escapes):
+    """The escaped tokens' indices that :func:`_resolve_escapes_scan` finds,
+    by candidate hops; None where the hops find fewer markers than the
+    payload length promises (a malformed payload: the walk itself runs)."""
     pos = _hop_markers(cands, start, n, width, n_escapes)
     esc_tok = (pos - start - width * np.arange(len(pos))) // n
     if len(esc_tok) == n_escapes and esc_tok[-1] < n_deltas:
+        return esc_tok
+    return None
+
+
+def _resolve_hops(cands, words, start, n_deltas, n, width, n_escapes,
+                  tally) -> tuple[np.ndarray, np.ndarray]:
+    """:func:`_resolve_escapes_scan`'s offsets and flags by candidate hops
+    (:func:`_hop_tokens`), else by the walk."""
+    esc_tok = _hop_tokens(cands, start, n_deltas, n, width, n_escapes)
+    if esc_tok is not None:
         tally[1] += 1
         return _dense(start, width, np.array([n]), np.array([n_deltas]),
                       np.array([n_escapes]), esc_tok)
     tally[2] += 1
     return _resolve_escapes_scan(words, start, n_deltas, n, width, n_escapes)
+
+
+def _fill_dense(rows: list, start: int, width: int, n_p, d_p, e_p,
+                esc_tok: np.ndarray) -> None:
+    """Fill the offsets and flags of the plans of ``rows`` (each row's last
+    item) in one :func:`_dense` pass: pages of token widths ``n_p``, token
+    counts ``d_p``, escape counts ``e_p``, escaped tokens ``esc_tok``."""
+    offs, flags = _dense(start, width, n_p, d_p, e_p, esc_tok)
+    ends = np.cumsum(d_p).tolist()
+    for row, lo, hi in zip(rows, [0] + ends[:-1], ends):
+        row[-1][4:6] = offs[lo:hi], flags[lo:hi]
 
 
 # _LOW_ONES[b] / _HIGH_ONES[b]: the set bits at the bottom / top of byte b,
@@ -504,11 +527,8 @@ def _resolve_closed(buf: np.ndarray, wide: list, start: int, width: int,
 
     gi = np.flatnonzero(good)
     if len(gi):  # the good pages' offsets and flags in one pass
-        offs, flags = _dense(start, width, n_p[gi], d_p[gi], e_p[gi],
-                             j[good[pg]])
-        ends = np.cumsum(d_p[gi]).tolist()
-        for q, lo, hi in zip(gi.tolist(), [0] + ends[:-1], ends):
-            wide[q][6][4:6] = offs[lo:hi], flags[lo:hi]
+        _fill_dense([wide[q] for q in gi.tolist()], start, width, n_p[gi],
+                    d_p[gi], e_p[gi], j[good[pg]])
         tally[0] += len(gi)
     for q in np.flatnonzero(~good).tolist():
         _, _, n, n_deltas, n_escapes, words, plan = wide[q]
@@ -520,6 +540,54 @@ def _resolve_closed(buf: np.ndarray, wide: list, start: int, width: int,
                  + np.arange(ends[-1] if len(ends) else 0, dtype=np.int64))
         plan[4:6] = _resolve_hops(cands, words, start, n_deltas, n, width,
                                   n_escapes, tally)
+
+
+def _resolve_narrow(buf_words: np.ndarray, narrow: list, start: int,
+                    width: int, tally: list) -> None:
+    """Resolve the escapes of many ``n* < 15`` pages by candidate hops, with
+    one :func:`marker_candidates` pass over the words from the first such
+    page to the last.
+
+    ``narrow`` holds ``[word offset in buf_words, word count (spill word
+    included), n, n_deltas, n_escapes, words, plan]`` per page, and each
+    plan's offsets and flags are filled in. A page ends in its zero spill
+    word, so no run of ones crosses a page. The pass looks for runs of the
+    smallest ``n``; a page of a larger ``n`` keeps the candidates with that
+    many ones, those at least ``n - n_min`` bits before the end of their
+    run. One ``searchsorted`` splits the candidates by page, and the hopped
+    pages' offsets and flags come from one :func:`_dense` pass.
+    """
+    info = np.array([w[:3] for w in narrow], dtype=np.int64)
+    base, size, n_p = 64 * info[:, 0], 64 * info[:, 1], info[:, 2]
+    lo = int(info[0, 0])
+    n_min = int(n_p.min())
+    cands = marker_candidates(buf_words[lo : lo + int(info[-1, :2].sum())],
+                              n_min) + 64 * lo
+    pg = np.searchsorted(base, cands, side="right") - 1
+    keep = cands < base[pg] + size[pg]  # not in a page between them
+    if len(cands) and (n_p != n_min).any():
+        brk = np.flatnonzero(np.diff(cands) != 1) + 1  # where runs start
+        run = np.zeros(len(cands), dtype=np.int64)
+        run[brk] = 1
+        run_end = cands[np.append(brk - 1, len(cands) - 1)][np.cumsum(run)]
+        keep &= run_end - cands >= n_p[pg] - n_min
+    cands, pg = cands[keep], pg[keep]
+    cuts = np.searchsorted(pg, np.arange(len(narrow) + 1)).tolist()
+    good, toks = [], []
+    for q, (_, _, n, n_deltas, n_escapes, words, plan) in enumerate(narrow):
+        esc_tok = _hop_tokens(cands[cuts[q] : cuts[q + 1]] - base[q], start,
+                              n_deltas, n, width, n_escapes)
+        if esc_tok is None:
+            tally[2] += 1
+            plan[4:6] = _resolve_escapes_scan(words, start, n_deltas, n,
+                                              width, n_escapes)
+            continue
+        good.append(narrow[q])
+        toks.append(esc_tok)
+    if good:
+        info = np.array([row[2:5] for row in good], dtype=np.int64)
+        _fill_dense(good, start, width, *info.T, np.concatenate(toks))
+        tally[1] += len(good)
 
 
 @dataclass(frozen=True)
@@ -597,6 +665,7 @@ def fp_delta_plan_many(payloads, counts, dtype) -> list[FPDeltaPlan]:
     buf = buf_words.view(np.uint8)
     plans = []  # FPDeltaPlan fields per page, from n on
     wide = []   # [byte offset, byte size, n, n_deltas, n_escapes, words, plan]
+    narrow = []  # [word offset, word count, n, n_deltas, n_escapes, words, plan]
     tally = [0, 0, 0]  # pages resolved closed, by hops, by the walk
     w0 = 0
     for payload, n_values, size, nw in zip(payloads, counts, sizes, n_words):
@@ -636,10 +705,11 @@ def fp_delta_plan_many(payloads, counts, dtype) -> list[FPDeltaPlan]:
             wide.append([8 * (w0 - nw), 8 * nw, n, n_deltas, n_escapes,
                          words, plan])
             continue
-        plan[4:6] = _resolve_hops(marker_candidates(words, n), words, start,
-                                 n_deltas, n, width, n_escapes, tally)
+        narrow.append([w0 - nw, nw, n, n_deltas, n_escapes, words, plan])
     if wide:
         _resolve_closed(buf, wide, start, width, tally)
+    if narrow:
+        _resolve_narrow(buf_words, narrow, start, width, tally)
     if obs.enabled():
         for name, k in zip(("closed", "hop", "walk"), tally):
             if k:
@@ -696,6 +766,155 @@ def fp_delta_execute(plan: FPDeltaPlan, out: np.ndarray | None = None) -> np.nda
     out_int[1 : 1 + esc_idx[0]] = running[: esc_idx[0]]
     out_int[1 + esc_idx[0] :] = running[esc_idx[0] :] + np.repeat(corr, reps)
     return out_arr
+
+
+_U1, _U63 = np.uint64(1), np.uint64(63)
+# pages executed together, at most this many values at a time (a page more
+# is its own group): a group's temporaries stay in the cache, and on the
+# heap, where a fresh large array would cost a page fault per 4 KiB
+_GROUP_VALUES = 1 << 15
+
+
+def fp_delta_execute_many(plans, out: np.ndarray) -> np.ndarray:
+    """:func:`fp_delta_execute` of many plans of one dtype into ``out``, the
+    pages laid end to end (``out`` holds the sum of their ``n_values``).
+
+    Bit-identical to executing each plan into its slice, in a fixed number
+    of whole-array passes over a group of consecutive FP-delta pages
+    (:func:`_execute_group`). A raw-mode page, and a group of one page, is
+    executed alone.
+    """
+    plans = list(plans)
+    dtype = plans[0].dtype if plans else out.dtype
+    if any(p.dtype != dtype for p in plans):
+        raise ValueError("plans must share one dtype")
+    _check_out(out, sum(p.n_values for p in plans), dtype)
+    plans = [p for p in plans if p.n_values]
+    o = q = 0
+    while q < len(plans):
+        g, size = q + 1, plans[q].n_values
+        while (plans[q].n and g < len(plans) and plans[g].n
+               and size + plans[g].n_values <= _GROUP_VALUES):
+            size += plans[g].n_values
+            g += 1
+        if g - q == 1:
+            fp_delta_execute(plans[q], out=out[o : o + size])
+        else:
+            for k, o_k in _execute_group(plans[q:g], out[o : o + size]):
+                plan = plans[q + k]
+                fp_delta_execute(plan, out=out[o + o_k :
+                                               o + o_k + plan.n_values])
+        o, q = o + size, g
+    return out
+
+
+def _execute_group(plans, out: np.ndarray) -> list:
+    """Execute non-empty FP-delta plans (``n > 0``) laid end to end in
+    ``out``; return ``(index, first value)`` of each plan left to execute
+    alone: one whose offsets the counts cannot rebuild, which rides along
+    as zero deltas (:func:`_group_escapes`).
+
+    Every value gets a slot, a page's first value too. One segmented cumsum
+    finishes, where page starts (restarting at the page's ``first``) and
+    escapes (restarting at their raw value) are the resets: a reset's slot
+    takes the value that lands the running sum on its target, from each
+    segment's sum (``np.add.reduceat``). The resets come in slot order from
+    the counts, with no sort, and give every slot's bit offset too: ``n``
+    bits a slot within a page, plus ``W`` past each marker.
+    """
+    width = plans[0].width
+    nv = np.array([p.n_values for p in plans], dtype=np.int64)
+    nn = np.array([p.n for p in plans], dtype=np.int64)
+    n_slots = int(nv.sum())
+    slot0 = np.cumsum(nv) - nv  # each page's first value
+    alone: list = []
+    pg, tok = _group_escapes(plans, nv - 1, nn, alone)
+
+    # slot s of page p reads at n*s + const, the const growing by W past
+    # each marker; a page's first slot reads inside its first value
+    n_words = np.array([len(p.words) for p in plans], dtype=np.int64)
+    words = np.concatenate([p.words for p in plans])
+    const = (64 * (np.cumsum(n_words) - n_words) + HEADER_BITS + width
+             - nn * (slot0 + 1))
+    firsts = np.array([p.first for p in plans], dtype=np.uint64).view(np.int64)
+    if len(pg):
+        # resets in slot order: page p's start after the escapes of pages
+        # < p, each escape after its own page's start
+        n_esc = np.bincount(pg, minlength=len(plans))
+        esc0 = np.cumsum(n_esc) - n_esc  # each page's first escape
+        at_start = np.arange(len(plans)) + esc0
+        at_esc = np.arange(len(pg)) + pg + 1
+        resets = np.empty(len(plans) + len(pg), dtype=np.int64)
+        resets[at_start] = slot0
+        resets[at_esc] = slot0[pg] + 1 + tok
+        seg_start = resets.copy()
+        seg_start[at_esc] += 1  # the marker itself reads before its raw value
+        seg_const = np.empty(len(resets), dtype=np.int64)
+        seg_const[at_start] = const
+        seg_const[at_esc] = const[pg] + width * (at_esc - pg - esc0[pg])
+        target = np.empty(len(resets), dtype=np.int64)
+        target[at_start] = firsts
+    else:
+        resets = seg_start = slot0
+        seg_const, target = const, firsts
+    uniform = bool((nn == nn[0]).all())
+    if uniform and nn[0]:  # (all 0 where every page rides along)
+        offs = np.arange(0, int(nn[0]) * n_slots, int(nn[0]), dtype=np.int64)
+    else:
+        offs = np.repeat(nn, nv)
+        offs *= np.arange(n_slots, dtype=np.int64)
+    offs += np.repeat(seg_const, np.append(seg_start[1:], n_slots) - seg_start)
+    if len(pg):
+        target[at_esc] = unpack_at(words, offs[resets[at_esc]] + nn[pg],
+                                   width).view(np.int64)
+
+    # one gather of every slot's token, un-zigzagged in place
+    wi = offs >> 6
+    sh = np.bitwise_and(offs, 63, out=offs).view(np.uint64)
+    z = np.take(words, wi)
+    z >>= sh
+    wi += 1
+    hi = np.take(words, wi)
+    hi <<= _U1
+    hi <<= np.subtract(_U63, sh, out=sh)  # 64 - shift, without a shift by 64
+    z |= hi
+    masks = width_mask(nn)
+    z &= masks[0] if uniform else np.repeat(masks, nv)
+    odd = np.bitwise_and(z, _U1, out=hi)
+    z >>= _U1
+    z ^= np.negative(odd, out=odd)
+    d = z.view(np.int64)
+
+    d[resets] = 0
+    seg = np.add.reduceat(d, resets)  # each segment's deltas
+    jump = target.copy()
+    jump[1:] -= target[:-1] + seg[:-1]
+    d[resets] = jump
+    np.cumsum(d, dtype=_SIGNED[width], out=out.view(_SIGNED[width]))
+    return [(q, int(slot0[q])) for q in alone]
+
+
+def _group_escapes(plans, nd: np.ndarray, nn: np.ndarray, alone: list):
+    """Page and token index of every marker flag of ``plans`` (token counts
+    ``nd``), page after page. A plan with more flags than its escape count
+    (a malformed payload's fixpoint clipped its offsets there, so the
+    counts cannot rebuild them) joins ``alone``, its ``nn`` set to 0."""
+    esc_pages = [q for q, p in enumerate(plans) if p.n_escapes]
+    if not esc_pages:
+        return _EMPTY_OFFS, _EMPTY_OFFS
+    ep = np.array(esc_pages, dtype=np.int64)
+    flat = np.flatnonzero(np.concatenate([plans[q].flags for q in esc_pages]))
+    head = np.cumsum(nd[ep]) - nd[ep]
+    at = np.searchsorted(head, flat, side="right") - 1
+    pg, tok = ep[at], flat - head[at]
+    limit = np.array([plans[q].n_escapes for q in esc_pages])
+    bad = ep[np.bincount(at, minlength=len(ep)) > limit]
+    if len(bad):
+        alone += bad.tolist()
+        nn[bad] = 0
+        keep = nn[pg] > 0
+        pg, tok = pg[keep], tok[keep]
+    return pg, tok
 
 
 def fp_delta_decode(

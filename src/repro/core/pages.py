@@ -29,6 +29,7 @@ from .fp_delta import (
     fp_delta_decode,
     fp_delta_encode,
     fp_delta_encode_pages,
+    fp_delta_execute_many,
     fp_delta_plan,
     fp_delta_plan_many,
 )
@@ -172,6 +173,46 @@ def decode_page(
             return out
         return vals.copy()
     raise ValueError(f"unknown encoding {meta.encoding!r}")
+
+
+def decode_pages(pages, dtype, codec: str, out: np.ndarray) -> np.ndarray:
+    """:func:`decode_page` of ``(buf, meta)`` pages of one column, in order,
+    into ``out``, which holds the sum of their counts (same strict contract:
+    dtype, 1-D, C-contiguous, length, checked before any byte is parsed).
+
+    The FP-delta pages are planned together
+    (:func:`~repro.core.fp_delta.fp_delta_plan_many`) and each run of
+    consecutive ones executed in one call
+    (:func:`~repro.core.fp_delta.fp_delta_execute_many`); a raw page is a
+    ``frombuffer`` copy into its slice.
+    """
+    dtype = np.dtype(dtype)
+    counts = [meta.count for _, meta in pages]
+    _check_out(out, sum(counts), dtype)
+    payloads, fp_counts = [], []
+    runs = []  # [first value, values, pages] of each run of FP-delta pages
+    o = 0
+    for (buf, meta), cnt in zip(pages, counts):
+        payload = decompress(buf, codec)
+        if meta.encoding == ENC_FP_DELTA:
+            if not runs or runs[-1][0] + runs[-1][1] != o:
+                runs.append([o, 0, 0])
+            runs[-1][1] += cnt
+            runs[-1][2] += 1
+            payloads.append(payload)
+            fp_counts.append(cnt)
+        elif meta.encoding == ENC_RAW:
+            out[o : o + cnt] = np.frombuffer(payload, dtype=dtype, count=cnt)
+        else:
+            raise ValueError(f"unknown encoding {meta.encoding!r}")
+        o += cnt
+    if payloads:
+        plans = fp_delta_plan_many(payloads, fp_counts, dtype)
+        q = 0
+        for v0, nv, n_pages in runs:
+            fp_delta_execute_many(plans[q : q + n_pages], out[v0 : v0 + nv])
+            q += n_pages
+    return out
 
 
 def page_plan(buf, meta: PageMeta, dtype, codec: str) -> FPDeltaPlan:

@@ -111,6 +111,7 @@ from .pages import (
     ENC_FP_DELTA,
     PageMeta,
     decode_page,
+    decode_pages,
     decompress,
     page_plan,
     page_stream_plans,
@@ -521,29 +522,34 @@ class SpatialParquetReader:
                                np.flatnonzero(rep == 0),
                                np.flatnonzero(type_rep == 0))
 
-    def _decode_run_extras(self, src, extra_pages, extra_all, we: int,
-                           p0: int, p1: int, stats: ReadStats) -> None:
-        """Decode one run's extra-column pages into the preallocated columns
-        at record cursor ``we`` (an ``rg.extras`` span; each column's page
-        checksums are one ``rg.crc`` child, checked before any decode)."""
-        with obs.span("rg.extras", cat="decode", pages=p1 - p0):
+    def _decode_extras(self, src, extra_pages, extra_all, we: int, runs,
+                       stats: ReadStats) -> None:
+        """Decode one row group's extra-column pages of the page runs
+        ``runs`` into the preallocated columns from record cursor ``we``, a
+        column at a time (one ``rg.extras`` span). Every page's checksum is
+        verified first, a column's pages in one ``rg.crc`` child; then each
+        column's pages decode in one batch (:func:`decode_pages`), counted
+        in ``reader.extras_pages`` and ``reader.extras_batches``."""
+        n_pages = sum(p1 - p0 for p0, p1 in runs)
+        with obs.span("rg.extras", cat="decode", pages=n_pages):
+            batches = []
             for k, ep in extra_pages.items():
-                with obs.span("rg.crc", cat="plan", pages=p1 - p0):
+                with obs.span("rg.crc", cat="plan", pages=n_pages):
                     pages = []
-                    for p in range(p0, p1):
-                        meta = PageMeta.from_dict(ep[p])
-                        pages.append((meta, self._checked_blob(
-                            src, meta.offset, meta.nbytes, meta.crc, stats,
-                            f"extra column {k!r} page {p}")))
-                wk = we
-                for meta, blob in pages:
-                    decode_page(
-                        blob, meta,
-                        np.dtype(self.extra_schema[k]), self.codec,
-                        out=extra_all[k][wk : wk + meta.count],
-                    )
-                    stats.bytes_read += meta.nbytes
-                    wk += meta.count
+                    for p0, p1 in runs:
+                        for p in range(p0, p1):
+                            meta = PageMeta.from_dict(ep[p])
+                            pages.append((self._checked_blob(
+                                src, meta.offset, meta.nbytes, meta.crc, stats,
+                                f"extra column {k!r} page {p}"), meta))
+                batches.append((k, pages))
+            for k, pages in batches:
+                n = sum(meta.count for _, meta in pages)
+                decode_pages(pages, np.dtype(self.extra_schema[k]), self.codec,
+                             out=extra_all[k][we : we + n])
+                stats.bytes_read += sum(meta.nbytes for _, meta in pages)
+            obs.count("reader.extras_pages", n_pages * len(batches))
+            obs.count("reader.extras_batches", len(batches))
 
     def _coord_pages(self, src, rg, rg_i: int, base: int, p0: int, p1: int,
                      stats: ReadStats) -> list[tuple]:
@@ -823,6 +829,7 @@ class SpatialParquetReader:
 
                 with obs.span("rg.decode", cat="decode", rg=rg_i,
                               device=device):
+                    we0 = we
                     for p0, p1 in runs:
                         j0, j1 = base + p0, base + p1 - 1
                         r0 = int(idx.rec_start[j0])
@@ -840,9 +847,9 @@ class SpatialParquetReader:
                                 + idx.y_nbytes[j0 : j1 + 1].sum()
                             )
                             lv.append_run(level_parts, r0, r1)
-                        self._decode_run_extras(src, extra_pages, extra_all,
-                                                we, p0, p1, stats)
                         we += r1 - r0
+                    self._decode_extras(src, extra_pages, extra_all, we0,
+                                        runs, stats)
 
                 if deferred:
                     # one batched page-stream launch per row group; decoded
@@ -994,9 +1001,9 @@ class SpatialParquetReader:
                         vc_parts.append(rec_vcounts_rg[r0:r1])
                         local_base += r1 - r0
                         lv.append_run(level_parts, r0, r1)
-                        self._decode_run_extras(src, extra_pages, extra_all, we,
-                                                p0, p1, stats)
                         we += r1 - r0
+                    self._decode_extras(src, extra_pages, extra_all, we0,
+                                        runs, stats)
                     plan_span.add(pages=len(pairs))
                 rec_vcounts = (np.concatenate(vc_parts) if vc_parts
                                else np.zeros(0, np.int64))
@@ -1163,8 +1170,7 @@ class SpatialParquetReader:
                 k: np.empty(n_rec, np.dtype(self.extra_schema[k]))
                 for k in want_extra
             }
-            self._decode_run_extras(src, extra_pages, extra_all, 0,
-                                    0, n_pages, stats)
+            self._decode_extras(src, extra_pages, extra_all, 0, runs, stats)
             if n_pages:
                 j0, j1 = base, base + n_pages - 1
                 stats.bytes_read += int(idx.x_nbytes[j0 : j1 + 1].sum()

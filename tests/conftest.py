@@ -15,7 +15,7 @@ def rng():
     return np.random.default_rng(0)
 
 
-@pytest.fixture
+@pytest.fixture(scope="session")
 def bench_data():
     """``(cfg, data)`` of a benchmark configuration at ``n_records``, made by
     its generator in ``perfbench/configs`` from ``seed``."""
