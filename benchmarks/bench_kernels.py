@@ -56,10 +56,14 @@ def run(scale: float = 1.0) -> list[dict]:
     plans = [fp_delta_plan(payload, v1 - v0, np.float64)
              for (payload, _), (v0, v1) in zip(
                  fp_delta_encode_pages(x64, bounds), bounds)]
-    s, _ = _throughput(lambda: fpd.decode_pages(plans, use_pallas=True))
+    def stream_decode(use_pallas):
+        return fpd.decode_page_stream(fpd.build_page_stream(plans),
+                                      use_pallas=use_pallas)
+
+    s, _ = _throughput(stream_decode, True)
     rows.append(dict(table="K", name="stream_decode64_interpret",
                      mbps=x64.nbytes / s / 1e6, n=len(x64), pages=n_pages))
-    s, _ = _throughput(lambda: fpd.decode_pages(plans, use_pallas=False))
+    s, _ = _throughput(stream_decode, False)
     rows.append(dict(table="K", name="stream_decode64_ref",
                      mbps=x64.nbytes / s / 1e6, n=len(x64), pages=n_pages))
 

@@ -30,7 +30,6 @@ from .fp_delta import (
     fp_delta_encode,
     fp_delta_encode_pages,
     fp_delta_execute_many,
-    fp_delta_plan,
     fp_delta_plan_many,
 )
 
@@ -155,8 +154,8 @@ def decode_page(
     """Decode one page; ``buf`` may be any bytes-like (memoryview slice).
 
     ``out``, if given, receives the decoded values in place (must be a
-    contiguous 1-D array of ``meta.count`` elements) — the coalesced reader
-    uses this to decode straight into preallocated column arrays.
+    contiguous 1-D array of ``meta.count`` elements). The reader decodes
+    through :func:`decode_pages`; this page-at-a-time form is its reference.
     """
     payload = decompress(buf, codec)
     if meta.encoding == ENC_FP_DELTA:
@@ -213,19 +212,6 @@ def decode_pages(pages, dtype, codec: str, out: np.ndarray) -> np.ndarray:
             fp_delta_execute_many(plans[q : q + n_pages], out[v0 : v0 + nv])
             q += n_pages
     return out
-
-
-def page_plan(buf, meta: PageMeta, dtype, codec: str) -> FPDeltaPlan:
-    """Host-resolve one stored page into an :class:`FPDeltaPlan`.
-
-    The front half of the device read path: decompress + header parse +
-    escape resolution on the host; the returned plan is what
-    ``repro.kernels.fp_delta.decode_pages`` batches onto the accelerator.
-    Only FP-delta pages have plans (raw pages are a plain ``frombuffer``).
-    """
-    if meta.encoding != ENC_FP_DELTA:
-        raise ValueError(f"page_plan requires fp_delta pages, got {meta.encoding!r}")
-    return fp_delta_plan(decompress(buf, codec), meta.count, dtype)
 
 
 def page_stream_plans(pages, dtype, codec: str) -> list[FPDeltaPlan]:

@@ -10,76 +10,75 @@ The reader exposes two access paths:
   rather than reading one value at a time"); it is our primary fast path and
   what the training data pipeline consumes.
 
-Coalesced-I/O architecture (the batched hot path)
--------------------------------------------------
+One planner, two executors
+--------------------------
 
-``read_columnar`` never reads one page at a time. Per row group it collects
-the ``(offset, nbytes)`` of every blob it needs — the four level streams,
-each run of consecutive hit x/y pages (runs come straight from
-``SpatialIndex.page_runs``, no Python-side grouping), and the matching extra
-column pages — merges byte ranges whose gap is at most ``coalesce_max_gap``,
-and issues exactly one ``seek`` + ``readinto`` per merged range into a
-preallocated buffer. Individual blobs are then zero-copy ``memoryview``
-slices of those buffers. For a full-file scan of one row group this is a
-single read syscall for the whole group.
+Every read goes the same three steps; only the last one differs by device.
 
-Row groups are **double-buffered** (``prefetch_row_groups``, default 1): a
-single reader thread issues row group N+1's coalesced reads while the main
-thread decodes row group N from already-filled buffers, so intra-file I/O
-overlaps decode exactly like the dataset scanner overlaps shards. Results
-are byte-identical to the sequential order (``prefetch_row_groups=0``
-disables the overlap; ``coalesce=False`` implies it).
+1. **Plan** (:meth:`SpatialParquetReader.plan`, metadata only): the page
+   index prunes by ``bbox`` and the filter's zone statistics, hit pages
+   group into runs per row group (``SpatialIndex.page_runs``), and the
+   ``ReadStats`` fields that metadata decides — pages and bytes in total
+   and read, records scanned — are counted once. The query server plans
+   each of its shards with the same method.
+2. **Prepare** each hit row group on the host: its level streams
+   (``rg.levels``), then under ``rg.plan`` each page run's coordinate pages
+   checksum-verified (``rg.crc``) with the record range of every page, and
+   the extra columns (``rg.extras``: every page's checksum, then one batched
+   decode a column). No page of a row group decodes before every checksum
+   of the row group has been verified.
+3. **Execute**, per row group:
 
-Decoding is allocation-lean to match: the total hit value count is known from
-the index, so the x/y (and extra) destination arrays are preallocated once
-and every page decodes straight into its slice via the ``out=`` contract of
-``decode_page``/``fp_delta_decode`` — no per-page list-append or trailing
-``np.concatenate`` over coordinates. Pass ``coalesce=False`` to force the
-legacy one-read-per-blob behaviour (same decode path, used by the
-equivalence tests).
+   * **host** (``device="cpu"``, the default and the oracle): the x pages
+     and the y pages decode in one batch an axis
+     (:func:`~repro.core.pages.decode_pages`, ``rg.decode``); then one keep
+     function: the per-record bbox test ∧ the attribute mask, then the
+     exact test (:mod:`repro.core.exact`) over what both keep.
+   * **device** (``device="jax"``): each page run's pages are planned into
+     stream plans (``rg.stream_plan``; raw pages through a synthetic
+     raw-mode plan, see ``pages.page_stream_plans``), page pairs are
+     chunked under the launch cap (``chunk_plan_pairs``) and each chunk
+     runs the launch chain (``rg.launch``): decode, and with ``refine``
+     the segmented per-record min/max and bbox test
+     (``decode_refine_stream``) with the attribute mask AND-ed in, then
+     with ``exact`` the compare-only stage of :mod:`repro.kernels.exact`.
+     Only the record mask and the surviving coordinates cross back
+     (``rg.gather``), or nothing with ``keep_on_device=True``
+     (:class:`~repro.core.columnar.DeviceCoords` columns). A page pair too
+     large for any launch decodes on the host and goes through the host
+     keep function. Integer coordinates decode on the device and refine
+     through the host keep function. Results are **bit-identical** to the
+     host executor.
 
-Accelerated decode (``device="jax"``)
--------------------------------------
+Both executors compact the kept records the same way
+(:func:`compact_records`). ``read_row_group`` runs the same preparation
+over every page of one row group, for the query server's decode cache.
 
-``read_columnar(device="jax")`` moves the FP-delta back half — fixed-width
-gather, escape injection, segmented cumsum, un-zigzag, float bitcast — onto
-the accelerator: the host still parses headers and resolves escapes
-(``fp_delta_plan``), then every surviving coordinate page of a row group is
-concatenated into one Pallas page-stream launch
-(``repro.kernels.fp_delta.decode_pages``). Results are **bit-identical** to
-the host path (asserted by tests/test_device_decode.py); raw-encoded pages,
-level streams, and extra columns stay on the host. Off-TPU the kernels run
-in interpret mode, so the full path is exercised in CPU CI.
+Coalesced I/O
+-------------
 
-Fused device refinement (``device="jax", refine=True``)
--------------------------------------------------------
-
-With both flags set, refinement runs *where the data decodes*: the same
-launch chain appends a segmented per-record min/max (``repro.kernels.minmax``
-over IEEE-754 order keys — uint32 limb math, so float64 refines without
-``jax_enable_x64``) and the bbox survivor test
-(``repro.kernels.fp_delta.decode_refine_stream``). Pruned records **never
-materialize on the host**: only the record mask and the surviving
-coordinates cross back (raw-encoded pages join the launch through a
-synthetic raw-mode plan, see ``pages.page_stream_plans``). The surviving
-record set is bit-identical to the host refine. ``keep_on_device=True``
-additionally leaves the surviving coordinates on the accelerator, returning
-:class:`~repro.core.columnar.DeviceCoords` columns for zero-copy handoff
-into downstream device consumers (``repro.data.pipeline``).
+Per row group the plan collects the ``(offset, nbytes)`` of every blob the
+read needs — the four level streams, each run of consecutive hit x/y pages,
+and the matching extra column pages — merges byte ranges whose gap is at
+most ``coalesce_max_gap``, and issues exactly one ``readinto`` per merged
+range (``rg.fetch``). Blobs are zero-copy ``memoryview`` slices of those
+buffers. Row groups are **double-buffered** (``prefetch_row_groups``,
+default 1): a reader thread fetches row group N+1 while the main thread
+works on row group N; results are byte-identical to the sequential order
+(``prefetch_row_groups=0`` disables the overlap; ``coalesce=False`` reads
+one blob at a time and implies it).
 
 Fault-tolerant storage boundary (``repro.io``)
 ----------------------------------------------
 
-The reader no longer touches a file handle directly: all I/O goes through a
-:class:`~repro.io.source.ByteRangeSource`. The default
-:class:`~repro.io.source.LocalFileSource` preserves the historical
-``seek``+``readinto``-per-merged-run behaviour byte-for-byte; passing
-``source=RemoteRangeSource(...)`` runs the identical read path against an
-object-store-style backend with retries, deadlines and a read-through block
-cache. Format-v2 files carry per-blob checksums which are verified on every
-stored blob *before* it is decompressed, planned or launched (host and
-device paths alike); a mismatch triggers one cache-bypassing re-fetch (which
-heals a poisoned block cache) and raises an attributed
+All I/O goes through a :class:`~repro.io.source.ByteRangeSource`. The
+default :class:`~repro.io.source.LocalFileSource` reads the local file;
+passing ``source=RemoteRangeSource(...)`` runs the identical read path
+against an object-store-style backend with retries, deadlines and a
+read-through block cache. Format-v2 files carry per-blob checksums which are
+verified on every stored blob *before* it is decompressed, planned or
+launched; a mismatch triggers one cache-bypassing re-fetch (which heals a
+poisoned block cache) and raises an attributed
 :class:`~repro.io.checksum.ChecksumError` only if the bytes are still wrong.
 All recoveries are counted in :class:`ReadStats` (``retries``, ``timeouts``,
 ``checksum_failures``, ``cache_hits``/``cache_misses``).
@@ -101,23 +100,15 @@ from repro import obs
 from repro.io.checksum import ChecksumError, checksum_fn, crc32c
 from repro.io.source import LocalFileSource
 
-from .columnar import DeviceCoords, GeometryColumns, assemble
+from .columnar import DeviceCoords, GeometryColumns, assemble, ragged_ranges
 from .exact import edge_partners, narrow as exact_narrow
 from .filters import Predicate, canonical_bbox, validate_predicate
 from .fp_delta import fp_delta_execute
 from .geometry import Geometry
 from .index import SpatialIndex
-from .pages import (
-    ENC_FP_DELTA,
-    PageMeta,
-    decode_page,
-    decode_pages,
-    decompress,
-    page_plan,
-    page_stream_plans,
-)
+from .pages import PageMeta, decode_pages, decompress, page_stream_plans
 from .rle import decode_levels, rle_decode
-from .writer import MAGIC, MAGIC_V2, permute_records
+from .writer import MAGIC, MAGIC_V2
 
 _LEVEL_NAMES = ("type", "type_rep", "rep", "defn")
 
@@ -278,9 +269,9 @@ class _DirectRanges:
 class _RowGroupLevels:
     """Decoded level streams of one row group + record start indices.
 
-    Owns the record-range slicing shared by the host and fused read loops,
-    so the two paths can never drift apart on level semantics (their
-    bit-identity is part of the fused-refine contract).
+    Owns the record-range slicing shared by the row-group preparation of
+    every read and by the query server, so no path can drift apart on level
+    semantics (their bit-identity is part of the fused-refine contract).
     """
 
     types: np.ndarray
@@ -325,8 +316,8 @@ class RowGroupChunk:
 
     ``kind == "dev"`` carries an *unlaunched* packed page stream plus its
     refine aux (record segmentation) — the serve tier fuses multi-query
-    refinement into the launch. ``kind == "host"`` carries decoded x/y
-    values for pages the device path cannot pack (host-fallback codecs).
+    refinement into the launch. ``kind == "host"`` carries host-decoded x/y
+    values of a page pair too large for any launch.
     ``rec_lo``/``rec_hi`` are the rg-local record range the chunk covers.
     """
 
@@ -358,6 +349,38 @@ class RowGroupData:
     x: np.ndarray | None = None
     y: np.ndarray | None = None
     chunks: list[RowGroupChunk] | None = None
+
+
+@dataclass
+class _RowGroupPlan:
+    """One hit row group of a file plan (metadata only): its page runs,
+    the row-group records each run covers, the extra columns' page
+    metadata, and the byte ranges and stored bytes its read fetches."""
+
+    rg_i: int
+    rg: dict
+    base: int                       # index position of the group's page 0
+    geometry: bool                  # levels and coordinate pages read
+    runs: list[tuple[int, int]]     # hit page runs [p0, p1)
+    rec_runs: list[tuple[int, int]]  # the records [r0, r1) of each run
+    extra_pages: dict
+    ranges: list[tuple[int, int]]
+    nbytes: int
+
+    @property
+    def n_records(self) -> int:
+        return sum(r1 - r0 for r0, r1 in self.rec_runs)
+
+
+@dataclass
+class _PreparedRowGroup:
+    """A row group after its host preparation (see ``_prepare_rg``)."""
+
+    levels: _RowGroupLevels | None
+    pages: list            # (meta_x, blob_x, meta_y, blob_y), verified
+    pairs: list            # each page's records, local to the read records
+    rec_vcounts: np.ndarray  # values of each read record
+    plans: list | None     # device executor: x, y stream plan of each page
 
 
 class SpatialParquetReader:
@@ -392,7 +415,7 @@ class SpatialParquetReader:
             self._verify = bool(verify_checksums) and self.checksum_algo is not None
             self._blob_crc = checksum_fn(self.checksum_algo) if self._verify else None
             self.index = SpatialIndex(self.footer)
-            self._data_bytes = self._total_data_bytes()
+            self._data_bytes = footer_data_bytes(self.footer)
         except Exception:
             # never leak the handle/source when construction fails mid-way
             self.close()
@@ -467,35 +490,38 @@ class SpatialParquetReader:
             return fresh
         raise ChecksumError(what, offset, nbytes, crc, got)
 
-    def _total_data_bytes(self) -> int:
-        return footer_data_bytes(self.footer)
-
-    def _rg_ranges(self, rg, runs, base, want_geom, extra_pages):
-        """Every byte range one row group's decode needs (metadata only)."""
+    def _rg_plan(self, rg_i: int, runs, extra, geometry: bool) -> _RowGroupPlan:
+        """The plan of one row group's page runs ``runs`` (metadata only):
+        the records each run covers, the byte ranges of every blob the read
+        needs and their stored bytes (levels and coordinate pages only
+        with ``geometry``; the pages of the extra columns ``extra``)."""
         idx = self.index
+        rg = self.footer["row_groups"][rg_i]
+        base = int(np.searchsorted(idx.row_group, rg_i, side="left"))
+        extra_pages = {k: rg["extra"][k] for k in extra}
         ranges: list[tuple[int, int]] = []
-        if want_geom:
-            ranges += [
-                (rg[name]["offset"], rg[name]["nbytes"]) for name in _LEVEL_NAMES
-            ]
+        rec_runs = []
+        nbytes = 0
+        if geometry:
+            ranges += [(rg[n]["offset"], rg[n]["nbytes"]) for n in _LEVEL_NAMES]
+            nbytes += sum(rg[n]["nbytes"] for n in _LEVEL_NAMES)
         for p0, p1 in runs:
-            if want_geom:
-                j0, j1 = base + p0, base + p1 - 1
-                ranges.append((
-                    int(idx.x_offset[j0]),
-                    int(idx.x_offset[j1] + idx.x_nbytes[j1] - idx.x_offset[j0]),
-                ))
-                ranges.append((
-                    int(idx.y_offset[j0]),
-                    int(idx.y_offset[j1] + idx.y_nbytes[j1] - idx.y_offset[j0]),
-                ))
+            j0, j1 = base + p0, base + p1 - 1
+            rec_runs.append((int(idx.rec_start[j0]),
+                             int(idx.rec_start[j1] + idx.rec_count[j1])))
+            if geometry:
+                for off, nb in ((idx.x_offset, idx.x_nbytes),
+                                (idx.y_offset, idx.y_nbytes)):
+                    ranges.append((int(off[j0]),
+                                   int(off[j1] + nb[j1] - off[j0])))
+                    nbytes += int(nb[j0 : j1 + 1].sum())
             for ep in extra_pages.values():
                 first, last = ep[p0], ep[p1 - 1]
-                ranges.append((
-                    first["offset"],
-                    last["offset"] + last["nbytes"] - first["offset"],
-                ))
-        return ranges
+                ranges.append((first["offset"],
+                               last["offset"] + last["nbytes"] - first["offset"]))
+                nbytes += sum(ep[p]["nbytes"] for p in range(p0, p1))
+        return _RowGroupPlan(rg_i, rg, base, geometry, list(runs), rec_runs,
+                             extra_pages, ranges, nbytes)
 
     def _level_blob(self, src, rg, name: str, stats: ReadStats):
         meta = rg[name]
@@ -504,23 +530,16 @@ class SpatialParquetReader:
                                   f"{name!r} level stream")
 
     def _decode_rg_levels(self, src, rg, stats: ReadStats) -> _RowGroupLevels:
-        """Decode one row group's four level streams from memory slices."""
+        """Decode one row group's four level streams (``rg.levels``)."""
         with obs.span("rg.levels", cat="decode"):
-            return self._decode_rg_levels_inner(src, rg, stats)
-
-    def _decode_rg_levels_inner(self, src, rg, stats: ReadStats) -> _RowGroupLevels:
-        types = rle_decode(
-            decompress(self._level_blob(src, rg, "type", stats), self.codec))
-        type_rep = decode_levels(
-            decompress(self._level_blob(src, rg, "type_rep", stats), self.codec))
-        rep = decode_levels(
-            decompress(self._level_blob(src, rg, "rep", stats), self.codec))
-        defn = decode_levels(
-            decompress(self._level_blob(src, rg, "defn", stats), self.codec))
-        stats.bytes_read += sum(rg[name]["nbytes"] for name in _LEVEL_NAMES)
-        return _RowGroupLevels(types, type_rep, rep, defn,
-                               np.flatnonzero(rep == 0),
-                               np.flatnonzero(type_rep == 0))
+            types, type_rep, rep, defn = (
+                (rle_decode if name == "type" else decode_levels)(
+                    decompress(self._level_blob(src, rg, name, stats),
+                               self.codec))
+                for name in _LEVEL_NAMES)
+            return _RowGroupLevels(types, type_rep, rep, defn,
+                                   np.flatnonzero(rep == 0),
+                                   np.flatnonzero(type_rep == 0))
 
     def _decode_extras(self, src, extra_pages, extra_all, we: int, runs,
                        stats: ReadStats) -> None:
@@ -547,16 +566,13 @@ class SpatialParquetReader:
                 n = sum(meta.count for _, meta in pages)
                 decode_pages(pages, np.dtype(self.extra_schema[k]), self.codec,
                              out=extra_all[k][we : we + n])
-                stats.bytes_read += sum(meta.nbytes for _, meta in pages)
             obs.count("reader.extras_pages", n_pages * len(batches))
             obs.count("reader.extras_batches", len(batches))
 
     def _coord_pages(self, src, rg, rg_i: int, base: int, p0: int, p1: int,
                      stats: ReadStats) -> list[tuple]:
         """``(meta_x, blob_x, meta_y, blob_y)`` of pages ``[p0, p1)`` of one
-        row group, every blob checksum-verified (one ``rg.crc`` span):
-        checksums gate the launch chain, so a corrupt page is caught here,
-        before any plan or Pallas kernel sees it."""
+        row group, every blob checksum-verified (one ``rg.crc`` span)."""
         idx = self.index
         xp, yp = rg["x_pages"], rg["y_pages"]
         out = []
@@ -586,6 +602,58 @@ class SpatialParquetReader:
                  for pair in ((blob_x, meta_x), (blob_y, meta_y))],
                 self.coord_dtype, self.codec)
 
+    def _prepare_rg(self, src, rgp: _RowGroupPlan, stats: ReadStats,
+                    extra_all: dict, we: int, level_parts=None, *,
+                    device: bool = False) -> _PreparedRowGroup:
+        """Every host step of one row group before any coordinate decode,
+        shared by both executors and ``read_row_group``.
+
+        With geometry: the level streams (``rg.levels``), then under
+        ``rg.plan`` each page run's coordinate pages, checksum-verified
+        (``rg.crc``) and, for the device executor, planned into stream
+        plans (``rg.stream_plan``), with the record range of each page; the
+        read records' levels are appended to ``level_parts`` when given.
+        Then the extra columns (``rg.extras``) decode into ``extra_all``
+        from record cursor ``we``.
+
+        Every checksum of the row group, coordinate and attribute pages
+        alike, is verified before any of its pages decodes, on both
+        executors: the attribute pages decode only after every coordinate
+        page's checksum and their own, and the executors decode (or launch)
+        coordinates only after this returns. Planning reads only verified
+        blobs.
+        """
+        idx = self.index
+        lv = self._decode_rg_levels(src, rgp.rg, stats) if rgp.geometry else None
+        pages: list = []
+        pairs: list[tuple[int, int]] = []
+        vc_parts: list[np.ndarray] = []
+        plans: list | None = [] if device else None
+        with obs.span("rg.plan", cat="plan", rg=rgp.rg_i) as plan_span:
+            if lv is not None:
+                vcounts = lv.record_value_counts()
+                local = 0
+                for (p0, p1), (r0, r1) in zip(rgp.runs, rgp.rec_runs):
+                    run = self._coord_pages(src, rgp.rg, rgp.rg_i, rgp.base,
+                                            p0, p1, stats)
+                    if device:
+                        self._stream_plans(run, plans)
+                    pages += run
+                    j0, j1 = rgp.base + p0, rgp.base + p1
+                    lo = idx.rec_start[j0:j1] - r0 + local
+                    pairs += zip(lo.tolist(),
+                                 (lo + idx.rec_count[j0:j1]).tolist())
+                    vc_parts.append(vcounts[r0:r1])
+                    if level_parts is not None:
+                        lv.append_run(level_parts, r0, r1)
+                    local += r1 - r0
+            self._decode_extras(src, rgp.extra_pages, extra_all, we,
+                                rgp.runs, stats)
+            plan_span.add(pages=len(pairs))
+        rec_vcounts = (np.concatenate(vc_parts) if vc_parts
+                       else np.zeros(0, np.int64))
+        return _PreparedRowGroup(lv, pages, pairs, rec_vcounts, plans)
+
     def _iter_sources(self, items, coalesce: bool):
         """Yield ``(item, src)`` per hit row group, double-buffering reads.
 
@@ -608,8 +676,8 @@ class SpatialParquetReader:
             # the "fetch" stage span: every readinto of one row group's
             # coalesced ranges (runs on the prefetch thread when enabled —
             # obs.submit hands the span context across)
-            with obs.span("rg.fetch", cat="io", rg=it[0]):
-                return _CoalescedRanges(self._source, it[-1],
+            with obs.span("rg.fetch", cat="io", rg=it.rg_i):
+                return _CoalescedRanges(self._source, it.ranges,
                                         self.coalesce_max_gap)
 
         lookahead = self.prefetch_row_groups
@@ -633,6 +701,34 @@ class SpatialParquetReader:
                 yield it, src
 
     # -------------------------------------------------------------- read API
+    def plan(self, bbox=None, filter: Predicate | None = None, extra=(),
+             geometry: bool = True):
+        """The file plan of a read (metadata only): the pages the index
+        keeps for ``bbox`` and ``filter``'s zone statistics, grouped into
+        page runs per hit row group, and the :class:`ReadStats` fields that
+        metadata decides — ``pages_total``, ``bytes_total``, ``pages_read``,
+        ``records_scanned`` and ``bytes_read`` (levels and coordinate pages
+        with ``geometry``; the pages of the extra columns ``extra``).
+
+        Returns ``(hit, row_groups, stats)``: the hit page indices, a
+        ``_RowGroupPlan`` per hit row group in file order, and the stats.
+        Executors add only what they observe: ``records_returned``,
+        checksum failures and retries, and the source's counters.
+        """
+        idx = self.index
+        hit = idx.query(bbox, filter=filter)
+        runs_by_rg: dict[int, list[tuple[int, int]]] = {}
+        for rg_i, p0, p1 in idx.page_runs(bbox, hit=hit):
+            runs_by_rg.setdefault(rg_i, []).append((p0, p1))
+        rgs = [self._rg_plan(rg_i, runs, extra, geometry)
+               for rg_i, runs in sorted(runs_by_rg.items())]
+        stats = ReadStats(
+            pages_total=len(idx), bytes_total=self._data_bytes,
+            pages_read=sum(p1 - p0 for r in rgs for p0, p1 in r.runs),
+            bytes_read=sum(r.nbytes for r in rgs),
+            records_scanned=sum(r.n_records for r in rgs))
+        return hit, rgs, stats
+
     def read_columnar(
         self,
         bbox=None,
@@ -657,17 +753,22 @@ class SpatialParquetReader:
         reading without zone pruning and masking afterwards — the record
         mask is ``bbox ∧ attrs`` when combined with ``refine``). Columns a
         filter needs are decoded as required but only returned when
-        requested. ``coalesce=False``
-        disables batched range I/O (one read per blob; identical results).
-        ``device="jax"`` decodes surviving FP-delta coordinate pages on the
-        accelerator (one Pallas page-stream launch per row group,
-        bit-identical results); combined with ``refine=True`` the per-record
-        bbox test also runs on-device and only surviving records transfer
-        back. ``keep_on_device=True`` (requires ``device="jax"``) returns
-        :class:`DeviceCoords` coordinate columns that never leave the
-        accelerator; it is a no-op when ``columns`` excludes geometry (extra
-        columns always decode on the host). ``"cpu"`` is the default and the
-        oracle.
+        requested. ``coalesce=False`` disables batched range I/O (one read
+        per blob; identical results).
+
+        The read is planned once (:meth:`plan`), each hit row group is
+        prepared on the host (levels, verified pages, extra columns), then
+        one of two executors decodes and keeps records. ``device="cpu"``
+        (the default and the oracle) decodes on the host, an axis of a row
+        group in one batch, and refines there. ``device="jax"`` decodes the
+        coordinate pages on the accelerator, one launch chain per row group
+        under the launch cap, bit-identical to the host; with
+        ``refine=True`` the per-record bbox test also runs on the device and
+        only surviving records transfer back. ``keep_on_device=True``
+        (requires ``device="jax"``) returns :class:`DeviceCoords` coordinate
+        columns that never leave the accelerator; it is a no-op when
+        ``columns`` excludes geometry (extra columns always decode on the
+        host).
 
         ``exact=True`` (with ``refine=True``) answers by geometry instead of
         MBR: a record is returned iff its closed point set meets the closed
@@ -718,188 +819,95 @@ class SpatialParquetReader:
         if exact and self.coord_dtype.kind != "f":
             raise ValueError("exact=True requires float coordinates")
         exact = exact and refine and bbox is not None
-        use_device = device == "jax"
-        if keep_on_device and not use_device:
+        if keep_on_device and device != "jax":
             raise ValueError("keep_on_device=True requires device='jax'")
         if filter is not None:
             validate_predicate(filter, self.extra_schema)
         want_geom = columns is None or "geometry" in columns
-        want_extra = (
-            list(self.extra_schema)
-            if columns is None
-            else [c for c in columns if c in self.extra_schema]
-        )
-        # columns the filter needs decode too, but are only *returned* when
-        # requested (trimmed below)
-        read_extra = list(want_extra)
-        if filter is not None:
-            read_extra += [c for c in sorted(filter.columns())
-                           if c not in want_extra]
-        idx = self.index
-        stats = ReadStats(pages_total=len(idx), bytes_total=self._data_bytes)
-        src_stats0 = self._source.stats.copy()
+        want_extra, read_extra = extra_columns(self.extra_schema, columns,
+                                               filter)
+        on_device = device == "jax" and want_geom
+        # the bbox a record's coordinates are refined against (None: none)
+        refine_box = bbox if refine and bbox is not None and want_geom else None
+        if (on_device and keep_on_device and refine_box is not None
+                and self.coord_dtype.kind != "f"):
+            raise ValueError("device refinement requires float coordinates")
+        do_compact = refine_box is not None or filter is not None
 
-        # group hit-page runs by row group (runs arrive in file order)
-        hit = idx.query(bbox, filter=filter)
+        hit, rgs, stats = self.plan(bbox, filter, read_extra, want_geom)
+        src_stats0 = self._source.stats.copy()
+        idx = self.index
         if filter is not None and obs.enabled():
             # coordinate bytes of pages the zone stats pruned beyond bbox
             zoned = np.setdiff1d(idx.query(bbox), hit, assume_unique=True)
             obs.count("pruned.zone_bytes", int(idx.nbytes[zoned].sum()))
-        runs_by_rg: dict[int, list[tuple[int, int]]] = {}
-        for rg_i, p0, p1 in idx.page_runs(bbox, hit=hit):
-            runs_by_rg.setdefault(rg_i, []).append((p0, p1))
-            stats.pages_read += p1 - p0
 
-        # per-row-group work items: (rg_i, rg, runs, base, extra_pages, ranges)
-        items = []
-        for rg_i, rg in enumerate(self.footer["row_groups"]):
-            runs = runs_by_rg.get(rg_i)
-            if not runs:
-                continue
-            base = int(np.searchsorted(idx.row_group, rg_i, side="left"))
-            extra_pages = {k: rg["extra"][k] for k in read_extra}
-            items.append((rg_i, rg, runs, base, extra_pages,
-                          self._rg_ranges(rg, runs, base, want_geom, extra_pages)))
-
-        fused = use_device and want_geom and (
-            keep_on_device or (refine and bbox is not None)
-            or (filter is not None and self.coord_dtype.kind == "f")
-        )
-        if fused and refine and bbox is not None and self.coord_dtype.kind != "f":
-            if keep_on_device:
-                raise ValueError("device refinement requires float coordinates")
-            fused = False  # exotic int coords: decode on device, refine on host
-        if fused:
-            out = self._read_columnar_fused(
-                bbox, refine, coalesce, keep_on_device, read_extra,
-                items, stats, hit, filter=filter, exact=exact)
-            if filter is not None:
-                geo_f, extras_f, stats_f = out
-                out = (geo_f, {k: extras_f[k] for k in want_extra}, stats_f)
-            self._fold_source_stats(stats, src_stats0)
-            return out
-
-        if use_device:
-            # lazy: keeps jax out of host-only read paths
-            from repro.kernels.fp_delta import decode_pages as _device_decode_pages
-
-        # preallocate coordinate destinations across every hit page
-        total_vals = int(idx.count[hit].sum()) if len(hit) else 0
-        x_all = np.empty(total_vals, self.coord_dtype) if want_geom else None
-        y_all = np.empty(total_vals, self.coord_dtype) if want_geom else None
         total_recs = int(idx.rec_count[hit].sum()) if len(hit) else 0
         extra_all = {
             k: np.empty(total_recs, np.dtype(self.extra_schema[k]))
             for k in read_extra
         }
-
-        types_parts: list[np.ndarray] = []
-        type_rep_parts: list[np.ndarray] = []
-        rep_parts: list[np.ndarray] = []
-        defn_parts: list[np.ndarray] = []
-        w = 0   # value write cursor into x_all / y_all
-        we = 0  # record write cursor into extra columns
-        level_parts = (types_parts, type_rep_parts, rep_parts, defn_parts)
-        src_iter = self._iter_sources(items, coalesce)
+        level_parts = ([], [], [], []) if want_geom else None
+        keep_parts: list[np.ndarray] = []
+        x_parts: list = []
+        y_parts: list = []
+        we = 0        # record write cursor into the extra columns
+        n_attr = 0    # read records the attribute predicate keeps
+        vals_pruned = 0  # values of dropped records (record-level pruning)
+        src_iter = self._iter_sources(rgs, coalesce)
         try:
-            for (rg_i, rg, runs, base, extra_pages, _ranges), src in src_iter:
-                xp, yp = rg["x_pages"], rg["y_pages"]
-                if want_geom:
-                    lv = self._decode_rg_levels(src, rg, stats)
-
-                deferred: list[tuple] = []  # (plan, dest array, dest offset)
-
-                def _coord_page(axis, page_dict, j, p, dest, off, cnt):
-                    """Decode one coordinate page now (host) or defer it to
-                    the row group's batched device launch (fp_delta only)."""
-                    meta = PageMeta.from_dict(page_dict)
-                    blob = self._checked_blob(
-                        src,
-                        int(idx.x_offset[j] if axis == "x" else idx.y_offset[j]),
-                        int(idx.x_nbytes[j] if axis == "x" else idx.y_nbytes[j]),
-                        meta.crc, stats,
-                        f"{axis} page {p} of row group {rg_i}")
-                    if use_device and meta.encoding == ENC_FP_DELTA:
-                        deferred.append(
-                            (page_plan(blob, meta, self.coord_dtype, self.codec),
-                             dest, off))
-                    else:
-                        decode_page(blob, meta, self.coord_dtype, self.codec,
-                                    out=dest[off : off + cnt])
-
-                with obs.span("rg.decode", cat="decode", rg=rg_i,
-                              device=device):
-                    we0 = we
-                    for p0, p1 in runs:
-                        j0, j1 = base + p0, base + p1 - 1
-                        r0 = int(idx.rec_start[j0])
-                        r1 = int(idx.rec_start[j1] + idx.rec_count[j1])
-                        stats.records_scanned += r1 - r0
-                        if want_geom:
-                            for p in range(p0, p1):
-                                j = base + p
-                                cnt = int(idx.count[j])
-                                _coord_page("x", xp[p], j, p, x_all, w, cnt)
-                                _coord_page("y", yp[p], j, p, y_all, w, cnt)
-                                w += cnt
-                            stats.bytes_read += int(
-                                idx.x_nbytes[j0 : j1 + 1].sum()
-                                + idx.y_nbytes[j0 : j1 + 1].sum()
-                            )
-                            lv.append_run(level_parts, r0, r1)
-                        we += r1 - r0
-                    self._decode_extras(src, extra_pages, extra_all, we0,
-                                        runs, stats)
-
-                if deferred:
-                    # one batched page-stream launch per row group; decoded
-                    # bits are copied into the preallocated columns dtype-
-                    # blind (view) so float/int columns both stay bit-exact
-                    with obs.span("rg.launch", cat="device", rg=rg_i,
-                                  pages=len(deferred)):
-                        outs = _device_decode_pages([p for p, _, _ in deferred])
-                        for (plan, dest, off), vals in zip(deferred, outs):
-                            dest[off : off + plan.n_values] = vals.view(dest.dtype)
+            for rgp, src in src_iter:
+                lv0 = len(level_parts[0]) if want_geom else 0
+                prep = self._prepare_rg(src, rgp, stats, extra_all, we,
+                                        level_parts, device=on_device)
+                n_rec = rgp.n_records
+                attr = None
+                if filter is not None:
+                    # the attribute mask over this row group's read records
+                    attr = filter.mask({k: extra_all[k][we : we + n_rec]
+                                        for k in filter.columns()})
+                    n_attr += int(attr.sum())
+                we += n_rec
+                if not want_geom:
+                    if attr is not None:
+                        keep_parts.append(attr)
+                    continue
+                edges = None
+                if exact:
+                    # edges of the read records only, from their levels
+                    edges = edge_partners(*(np.concatenate(parts[lv0:])
+                                            for parts in (level_parts[2],
+                                                          level_parts[3],
+                                                          level_parts[0])))
+                if on_device:
+                    parts = self._execute_device(rgp, prep, refine_box, attr,
+                                                 edges, keep_on_device)
+                else:
+                    parts = [self._execute_host(rgp, prep, refine_box, attr,
+                                                edges)]
+                for keep_c, xs, ys, vc in parts:
+                    if do_compact:
+                        keep_parts.append(keep_c)
+                        if obs.enabled():
+                            vals_pruned += int(vc.sum() - vc[keep_c].sum())
+                    x_parts.append(xs)
+                    y_parts.append(ys)
         finally:
             src_iter.close()
+        if want_geom and do_compact:
+            obs.count("pruned.record_bytes",
+                      vals_pruned * 2 * self.coord_dtype.itemsize)
+        if filter is not None and we and obs.enabled():
+            obs.observe("filter.selectivity", n_attr / we)
 
-        if want_geom and types_parts:
-            geo = GeometryColumns(
-                np.concatenate(types_parts),
-                np.concatenate(type_rep_parts),
-                np.concatenate(rep_parts),
-                np.concatenate(defn_parts),
-                x_all[:w], y_all[:w],
-            )
-        else:
-            geo = None
-        extras = {k: v[:we] for k, v in extra_all.items()}
-        keep_mask = None
-        if refine and bbox is not None and geo is not None:
-            with obs.span("refine.host", cat="refine"):
-                starts = geo.record_value_starts()
-                counts = np.diff(np.append(starts, geo.n_values))
-                keep_mask = _bbox_keep_mask(geo.x, geo.y, counts, bbox)
-        if filter is not None:
-            attr = (filter.mask(extras) if we
+        keep = None
+        if do_compact:
+            keep = (np.concatenate(keep_parts) if keep_parts
                     else np.zeros(0, bool))
-            if we and obs.enabled():
-                obs.observe("filter.selectivity", float(attr.sum()) / we)
-            keep_mask = attr if keep_mask is None else keep_mask & attr
-        if exact and geo is not None:
-            # exact implies refine with a bbox: counts is refine.host's
-            with obs.span("refine.exact", cat="refine"):
-                off, poly = edge_partners(geo.rep, geo.defn, geo.types)
-                keep_mask = exact_narrow(geo.x, geo.y, counts, off, poly,
-                                         keep_mask, bbox)
-        if keep_mask is not None:
-            if geo is not None:
-                geo = permute_records(geo, np.flatnonzero(keep_mask))
-                obs.count("pruned.record_bytes",
-                          (w - geo.n_values) * 2 * self.coord_dtype.itemsize)
-            extras = {k: v[keep_mask] for k, v in extras.items()}
-        if filter is not None:
-            extras = {k: extras[k] for k in want_extra}
+        extras = {k: v[:we] for k, v in extra_all.items()}
+        geo, extras = compact_records(level_parts, keep, x_parts, y_parts,
+                                      extras, self.coord_dtype)
+        extras = {k: extras[k] for k in want_extra}
         stats.records_returned = geo.n_records if geo is not None else (
             len(next(iter(extras.values()))) if extras else 0
         )
@@ -915,28 +923,36 @@ class SpatialParquetReader:
         stats.cache_hits += d.cache_hits
         stats.cache_misses += d.cache_misses
 
-    # ------------------------------------------------------ fused device scan
-    def _read_columnar_fused(self, bbox, refine, coalesce, keep_on_device,
-                             want_extra, items, stats, hit, filter=None,
-                             exact=False):
-        """Decode → per-record bbox refine → compact, all device-resident.
+    # ------------------------------------------------------------ executors
+    def _execute_host(self, rgp: _RowGroupPlan, prep: _PreparedRowGroup,
+                      refine_box, attr, edges) -> tuple:
+        """The host executor over one prepared row group: its x pages, then
+        its y pages, decode in one batch each (``rg.decode``), then the host
+        keep function. Returns ``(keep, x, y, rec_vcounts)`` with the kept
+        records' coordinates (``keep`` None: every record kept)."""
+        vc = prep.rec_vcounts
+        n = int(vc.sum())
+        with obs.span("rg.decode", cat="decode", rg=rgp.rg_i, device="cpu"):
+            x, y = (decode_pages(axis, self.coord_dtype, self.codec,
+                                 out=np.empty(n, self.coord_dtype))
+                    for axis in ([(bx, mx) for mx, bx, _, _ in prep.pages],
+                                 [(by, my) for _, _, my, by in prep.pages]))
+        keep = _host_keep(x, y, vc, refine_box, attr, edges)
+        return (keep, *_kept_values(x, y, vc, keep), vc)
 
-        Per row group: levels decode on the host (they drive segmentation),
-        every hit coordinate page becomes a plan (raw pages via the synthetic
-        raw-mode plan) and joins one fused launch chain per chunk under the
-        launch cap (`decode_refine_stream`). Only the per-record survivor
-        mask and the surviving coordinate values cross back to the host — or
-        nothing at all with ``keep_on_device=True``.
+    def _execute_device(self, rgp: _RowGroupPlan, prep: _PreparedRowGroup,
+                        refine_box, attr, edges, keep_on_device: bool) -> list:
+        """The device executor over one prepared row group: its page pairs
+        chunked under the launch cap, each chunk one launch chain
+        (``rg.launch``) — decode, and with ``refine_box`` the per-record
+        bbox test with ``attr`` AND-ed into the record mask, then with
+        ``edges`` the exact stage — and a gather of the survivors
+        (``rg.gather``). A pair too large for any launch decodes on the host
+        (counted in ``device.host_fallback_pages``), and integer coordinates
+        decode on the device; both then go through the host keep function.
 
-        With ``filter`` the host-evaluated attribute mask is AND-ed into the
-        chunk's per-record ``valid`` operand before the launch, so the device
-        computes ``bbox ∧ attrs`` in one pass and survivor compaction (the
-        gather back to the host) already excludes records the predicate
-        rejects.
-
-        With ``exact`` the records the refine keeps go on to the exact
-        stage (:func:`repro.kernels.exact.exact_narrow`) inside the same
-        ``rg.launch``; ``build_refine_aux`` then carries their edges.
+        Returns ``(keep, x, y, rec_vcounts)`` a chunk, in record order, with
+        the kept records' coordinates.
         """
         from repro.kernels.exact import exact_narrow as exact_narrow_device
         from repro.kernels.fp_delta import (
@@ -946,191 +962,78 @@ class SpatialParquetReader:
             decode_refine_stream,
             decode_stream_device,
             gather_stream_values,
-            ragged_ranges,
         )
 
-        idx = self.index
         dtype = self.coord_dtype
         width = dtype.itemsize * 8
-        do_refine = refine and bbox is not None
-        do_compact = do_refine or filter is not None
-
-        total_recs = int(idx.rec_count[hit].sum()) if len(hit) else 0
-        extra_all = {
-            k: np.empty(total_recs, np.dtype(self.extra_schema[k]))
-            for k in want_extra
-        }
-        types_parts: list[np.ndarray] = []
-        type_rep_parts: list[np.ndarray] = []
-        rep_parts: list[np.ndarray] = []
-        defn_parts: list[np.ndarray] = []
-        keep_parts: list[np.ndarray] = []
-        x_parts: list = []
-        y_parts: list = []
-        we = 0
-
-        level_parts = (types_parts, type_rep_parts, rep_parts, defn_parts)
-        vals_pruned = 0  # refine-dropped values (record-level byte pruning)
-        src_iter = self._iter_sources(items, coalesce)
-        try:
-            for (rg_i, rg, runs, base, extra_pages, _ranges), src in src_iter:
-                lv = self._decode_rg_levels(src, rg, stats)
-                rec_vcounts_rg = lv.record_value_counts()
-                we0 = we  # this row group's record span in the extra columns
-                lv0 = len(rep_parts)  # this row group's first level part
-
-                plans: list = []            # x,y plan per page, stream order
-                pairs: list[tuple[int, int]] = []   # local record range per pair
-                vc_parts: list[np.ndarray] = []
-                local_base = 0
-                plan_span = obs.span("rg.plan", cat="plan", rg=rg_i)
-                with plan_span:
-                    for p0, p1 in runs:
-                        j0, j1 = base + p0, base + p1 - 1
-                        r0 = int(idx.rec_start[j0])
-                        r1 = int(idx.rec_start[j1] + idx.rec_count[j1])
-                        stats.records_scanned += r1 - r0
-                        self._stream_plans(self._coord_pages(
-                            src, rg, rg_i, base, p0, p1, stats), plans)
-                        for j in range(j0, j1 + 1):
-                            lo_loc = local_base + int(idx.rec_start[j]) - r0
-                            pairs.append((lo_loc, lo_loc + int(idx.rec_count[j])))
-                        stats.bytes_read += int(
-                            idx.x_nbytes[j0 : j1 + 1].sum() + idx.y_nbytes[j0 : j1 + 1].sum()
-                        )
-                        vc_parts.append(rec_vcounts_rg[r0:r1])
-                        local_base += r1 - r0
-                        lv.append_run(level_parts, r0, r1)
-                        we += r1 - r0
-                    self._decode_extras(src, extra_pages, extra_all, we0,
-                                        runs, stats)
-                    plan_span.add(pages=len(pairs))
-                rec_vcounts = (np.concatenate(vc_parts) if vc_parts
-                               else np.zeros(0, np.int64))
-                if exact:
-                    # edges of the read records only, from their levels
-                    off_read, poly_read = edge_partners(
-                        *(np.concatenate(parts[lv0:]) for parts in
-                          (rep_parts, defn_parts, types_parts)))
-                    vbound_read = np.concatenate([[0], np.cumsum(rec_vcounts)])
-                # host-evaluated attribute mask for this row group's read
-                # records (aligned with rec_vcounts / the chunk record ranges)
-                attr_rg = None
-                if filter is not None:
-                    attr_rg = filter.mask(
-                        {k: extra_all[k][we0:we] for k in filter.columns()})
-
-                # chunk page pairs into fused launches under the launch cap
-                for kind, cplans, cpairs, (rl, rh) in chunk_plan_pairs(plans, pairs):
-                    vc = rec_vcounts[rl:rh]
-                    attr_c = attr_rg[rl:rh] if attr_rg is not None else None
-                    edges_c = None
-                    if exact:
-                        v0, v1 = int(vbound_read[rl]), int(vbound_read[rh])
-                        edges_c = (off_read[v0:v1], poly_read[v0:v1])
-                    if kind == "host":
-                        # a single page too large for any launch: decode this
-                        # pair on the host (same bits via fp_delta_execute;
-                        # counted in device.host_fallback_pages)
-                        with obs.span("rg.launch", cat="decode", rg=rg_i,
-                                      kind="host"):
-                            x_v = fp_delta_execute(cplans[0])
-                            y_v = fp_delta_execute(cplans[1])
-                            keep_c = (_bbox_keep_mask(x_v, y_v, vc, bbox)
-                                      if do_refine else np.ones(len(vc), bool))
-                            if attr_c is not None:
-                                keep_c = keep_c & attr_c
-                            if exact:
-                                keep_c = exact_narrow(x_v, y_v, vc, *edges_c,
-                                                      keep_c, bbox)
-                            starts = np.cumsum(vc) - vc
-                            iv = ragged_ranges(starts[keep_c], vc[keep_c])
-                            xs, ys = x_v[iv], y_v[iv]
-                        if keep_on_device:
-                            xs = DeviceCoords.from_numpy(xs)
-                            ys = DeviceCoords.from_numpy(ys)
-                        if do_compact and obs.enabled():
-                            vals_pruned += int(vc.sum() - vc[keep_c].sum())
-                        keep_parts.append(keep_c)
-                        x_parts.append(xs)
-                        y_parts.append(ys)
-                        continue
-                    with obs.span("rg.launch", cat="device", rg=rg_i,
-                                  kind="refine" if do_refine else "decode",
-                                  pairs=len(cpairs)):
-                        with obs.span("launch.build", cat="plan"):
-                            stream = build_page_stream(cplans)
-                            aux = build_refine_aux(
-                                stream, [(a - rl, b - rl) for a, b in cpairs],
-                                vc, edges=edges_c)
-                            if attr_c is not None and do_refine:
-                                # the device record mask is valid ∧ bbox;
-                                # AND-ing the attribute mask into a fresh copy
-                                # of valid makes it bbox ∧ attrs in the same
-                                # launch
-                                v2 = aux.valid.copy()
-                                v2[:len(attr_c)] &= attr_c
-                                aux = dc_replace(aux, valid=v2)
-                        if do_refine:
-                            res = decode_refine_stream(stream, aux, bbox)
-                            keep_c, lo_d, hi_d = res.keep, res.lo, res.hi
-                            if exact:
-                                keep_c = exact_narrow_device(
-                                    lo_d, hi_d, aux, keep_c, bbox, width, dtype)
-                        else:
-                            lo_d, hi_d = decode_stream_device(stream)
-                            keep_c = (attr_c.copy() if attr_c is not None
-                                      else np.ones(len(vc), bool))
-                    if do_compact and obs.enabled():
-                        vals_pruned += int(vc.sum() - vc[keep_c].sum())
-                    keep_parts.append(keep_c)
-                    with obs.span("rg.gather", cat="transfer", rg=rg_i):
-                        ix = ragged_ranges(aux.x_start[keep_c], aux.counts[keep_c])
-                        iy = ragged_ranges(aux.y_start[keep_c], aux.counts[keep_c])
-                        x_parts.append(gather_stream_values(
-                            lo_d, hi_d, ix, width, dtype,
-                            keep_on_device=keep_on_device))
-                        y_parts.append(gather_stream_values(
-                            lo_d, hi_d, iy, width, dtype,
-                            keep_on_device=keep_on_device))
-        finally:
-            src_iter.close()
-        obs.count("pruned.record_bytes", vals_pruned * 2 * dtype.itemsize)
-
-        keep_all = (np.concatenate(keep_parts) if keep_parts
-                    else np.zeros(0, bool))
-        if types_parts:
-            types = np.concatenate(types_parts)
-            type_rep = np.concatenate(type_rep_parts)
-            rep = np.concatenate(rep_parts)
-            defn = np.concatenate(defn_parts)
-            if do_compact:
-                # record-aligned level subset == permute_records on the kept
-                # (sorted) records: canonical levels stay canonical
-                slot_keep = keep_all[np.cumsum(rep == 0) - 1]
-                type_keep = keep_all[np.cumsum(type_rep == 0) - 1]
-                types = types[type_keep]
-                type_rep = type_rep[type_keep]
-                rep = rep[slot_keep]
-                defn = defn[slot_keep]
-            if keep_on_device:
-                x = DeviceCoords.concat(x_parts)
-                y = DeviceCoords.concat(y_parts)
-            else:
-                x = np.concatenate(x_parts)
-                y = np.concatenate(y_parts)
-            geo = GeometryColumns(types, type_rep, rep, defn, x, y)
-        else:
-            geo = None
-        extras = {k: v[:we] for k, v in extra_all.items()}
-        if do_compact and geo is not None:
-            extras = {k: v[keep_all] for k, v in extras.items()}
-        if filter is not None and we and obs.enabled():
-            obs.observe("filter.selectivity", float(keep_all.sum()) / we)
-        stats.records_returned = geo.n_records if geo is not None else (
-            len(next(iter(extras.values()))) if extras else 0
-        )
-        return geo, extras, stats
+        rg_i = rgp.rg_i
+        dev_refine = refine_box is not None and dtype.kind == "f"
+        host_refine = refine_box is not None and not dev_refine
+        vc_rg = prep.rec_vcounts
+        if edges is not None:
+            vbound = np.concatenate([[0], np.cumsum(vc_rg)])
+        out = []
+        for kind, cplans, cpairs, (rl, rh) in chunk_plan_pairs(prep.plans,
+                                                              prep.pairs):
+            vc = vc_rg[rl:rh]
+            attr_c = attr[rl:rh] if attr is not None else None
+            edges_c = None
+            if edges is not None:
+                v0, v1 = int(vbound[rl]), int(vbound[rh])
+                edges_c = (edges[0][v0:v1], edges[1][v0:v1])
+            if kind == "host":
+                # a single pair too large for any launch: same bits via
+                # fp_delta_execute, same keep as the host executor
+                with obs.span("rg.launch", cat="decode", rg=rg_i, kind="host"):
+                    x_v = fp_delta_execute(cplans[0])
+                    y_v = fp_delta_execute(cplans[1])
+                    keep_c = _host_keep(x_v, y_v, vc, refine_box, attr_c,
+                                        edges_c)
+                    xs, ys = _kept_values(x_v, y_v, vc, keep_c)
+                if keep_c is None:
+                    keep_c = np.ones(len(vc), bool)
+                if keep_on_device:
+                    xs = DeviceCoords.from_numpy(xs)
+                    ys = DeviceCoords.from_numpy(ys)
+                out.append((keep_c, xs, ys, vc))
+                continue
+            with obs.span("rg.launch", cat="device", rg=rg_i,
+                          kind="refine" if dev_refine else "decode",
+                          pairs=len(cpairs)):
+                with obs.span("launch.build", cat="plan"):
+                    stream = build_page_stream(cplans)
+                    aux = build_refine_aux(
+                        stream, [(a - rl, b - rl) for a, b in cpairs],
+                        vc, edges=edges_c)
+                    if attr_c is not None and dev_refine:
+                        # the device record mask is valid ∧ bbox; AND-ing
+                        # the attribute mask into a fresh copy of valid
+                        # makes it bbox ∧ attrs in the same launch
+                        v2 = aux.valid.copy()
+                        v2[:len(attr_c)] &= attr_c
+                        aux = dc_replace(aux, valid=v2)
+                if dev_refine:
+                    res = decode_refine_stream(stream, aux, refine_box)
+                    keep_c, lo_d, hi_d = res.keep, res.lo, res.hi
+                    if edges_c is not None:
+                        keep_c = exact_narrow_device(
+                            lo_d, hi_d, aux, keep_c, refine_box, width, dtype)
+                else:
+                    lo_d, hi_d = decode_stream_device(stream)
+                    keep_c = (attr_c.copy() if attr_c is not None
+                              and not host_refine else np.ones(len(vc), bool))
+            with obs.span("rg.gather", cat="transfer", rg=rg_i):
+                ix = ragged_ranges(aux.x_start[keep_c], aux.counts[keep_c])
+                iy = ragged_ranges(aux.y_start[keep_c], aux.counts[keep_c])
+                xs = gather_stream_values(lo_d, hi_d, ix, width, dtype,
+                                          keep_on_device=keep_on_device)
+                ys = gather_stream_values(lo_d, hi_d, iy, width, dtype,
+                                          keep_on_device=keep_on_device)
+            if host_refine:
+                keep_c = _host_keep(xs, ys, vc, refine_box, attr_c, None)
+                xs, ys = _kept_values(xs, ys, vc, keep_c)
+            out.append((keep_c, xs, ys, vc))
+        return out
 
     # ---------------------------------------------- whole-row-group decode
     def read_row_group(self, rg_i: int, *, columns=None,
@@ -1143,57 +1046,32 @@ class SpatialParquetReader:
         exact [min, max]) computed from the full row group are bit-identical
         to the same record decoded through a bbox-pruned page run — the
         property that lets one decode serve queries whose page sets differ.
-        ``device="cpu"`` fills ``x``/``y`` host arrays; ``device="jax"``
-        returns *unlaunched* per-chunk page streams (the caller owns the
-        launch so it can fuse multi-query refinement into it).
+        The row group goes through the read path's own preparation;
+        ``device="cpu"`` then runs the host executor's decode into ``x``/
+        ``y``, and ``device="jax"`` returns *unlaunched* per-chunk page
+        streams (the caller owns the launch so it can fuse multi-query
+        refinement into it).
         """
         if device not in ("cpu", "jax"):
             raise ValueError(f"device must be 'cpu' or 'jax', got {device!r}")
-        idx = self.index
-        rg = self.footer["row_groups"][rg_i]
-        base = int(np.searchsorted(idx.row_group, rg_i, side="left"))
-        n_pages = len(rg["x_pages"])
-        want_extra = (list(self.extra_schema) if columns is None
-                      else [c for c in columns if c in self.extra_schema])
-        extra_pages = {k: rg["extra"][k] for k in want_extra}
-        runs = [(0, n_pages)]
-        stats = ReadStats()
+        n_pages = len(self.footer["row_groups"][rg_i]["x_pages"])
+        want_extra, _ = extra_columns(self.extra_schema, columns, None)
+        rgp = self._rg_plan(rg_i, [(0, n_pages)] if n_pages else [],
+                            want_extra, True)
         with obs.span("rg.read_full", cat="io", rg=rg_i, device=device):
-            src = _CoalescedRanges(
-                self._source,
-                self._rg_ranges(rg, runs, base, True, extra_pages),
-                self.coalesce_max_gap)
-            lv = self._decode_rg_levels(src, rg, stats)
-            rec_vcounts = lv.record_value_counts()
-            n_rec = lv.n_rec
-            extra_all = {
-                k: np.empty(n_rec, np.dtype(self.extra_schema[k]))
-                for k in want_extra
-            }
-            self._decode_extras(src, extra_pages, extra_all, 0, runs, stats)
-            if n_pages:
-                j0, j1 = base, base + n_pages - 1
-                stats.bytes_read += int(idx.x_nbytes[j0 : j1 + 1].sum()
-                                        + idx.y_nbytes[j0 : j1 + 1].sum())
-            rec0 = int(idx.rec_start[base]) if n_pages else 0
-
+            src = _CoalescedRanges(self._source, rgp.ranges,
+                                   self.coalesce_max_gap)
+            extra_all = {k: np.empty(rgp.n_records,
+                                     np.dtype(self.extra_schema[k]))
+                         for k in want_extra}
+            prep = self._prepare_rg(src, rgp, ReadStats(), extra_all, 0,
+                                    device=device == "jax")
+            data = RowGroupData(rg_i, prep.levels.n_rec, prep.rec_vcounts,
+                                prep.levels, extra_all, rgp.nbytes)
             if device == "cpu":
-                total_vals = int(idx.count[base : base + n_pages].sum())
-                x_all = np.empty(total_vals, self.coord_dtype)
-                y_all = np.empty(total_vals, self.coord_dtype)
-                w = 0
-                with obs.span("rg.decode", cat="decode", rg=rg_i, device="cpu"):
-                    pages = self._coord_pages(src, rg, rg_i, base, 0, n_pages,
-                                              stats)
-                    for p, (meta_x, blob_x, meta_y, blob_y) in enumerate(pages):
-                        cnt = int(idx.count[base + p])
-                        decode_page(blob_x, meta_x, self.coord_dtype,
-                                    self.codec, out=x_all[w : w + cnt])
-                        decode_page(blob_y, meta_y, self.coord_dtype,
-                                    self.codec, out=y_all[w : w + cnt])
-                        w += cnt
-                return RowGroupData(rg_i, n_rec, rec_vcounts, lv, extra_all,
-                                    stats.bytes_read, x=x_all, y=y_all)
+                _, data.x, data.y, _ = self._execute_host(rgp, prep, None,
+                                                          None, None)
+                return data
 
             from repro.kernels.fp_delta import (
                 build_page_stream,
@@ -1201,18 +1079,11 @@ class SpatialParquetReader:
                 chunk_plan_pairs,
             )
 
-            plans: list = []
-            pairs: list[tuple[int, int]] = []
-            with obs.span("rg.plan", cat="plan", rg=rg_i, pages=n_pages):
-                self._stream_plans(self._coord_pages(
-                    src, rg, rg_i, base, 0, n_pages, stats), plans)
-                for j in range(base, base + n_pages):
-                    r0 = int(idx.rec_start[j]) - rec0
-                    pairs.append((r0, r0 + int(idx.rec_count[j])))
-            chunks: list[RowGroupChunk] = []
-            for kind, cplans, cpairs, (rl, rh) in chunk_plan_pairs(plans, pairs):
+            data.chunks = []
+            for kind, cplans, cpairs, (rl, rh) in chunk_plan_pairs(
+                    prep.plans, prep.pairs):
                 if kind == "host":
-                    chunks.append(RowGroupChunk(
+                    data.chunks.append(RowGroupChunk(
                         "host", rl, rh,
                         x=fp_delta_execute(cplans[0]),
                         y=fp_delta_execute(cplans[1])))
@@ -1220,16 +1091,26 @@ class SpatialParquetReader:
                 stream = build_page_stream(cplans)
                 aux = build_refine_aux(
                     stream, [(a - rl, b - rl) for a, b in cpairs],
-                    rec_vcounts[rl:rh])
-                chunks.append(RowGroupChunk("dev", rl, rh,
-                                            stream=stream, aux=aux))
-            return RowGroupData(rg_i, n_rec, rec_vcounts, lv, extra_all,
-                                stats.bytes_read, chunks=chunks)
+                    prep.rec_vcounts[rl:rh])
+                data.chunks.append(RowGroupChunk("dev", rl, rh,
+                                                 stream=stream, aux=aux))
+            return data
 
     def read(self, bbox=None, refine: bool = False) -> tuple[list[Geometry], ReadStats]:
         """Object-API read returning Geometry instances."""
         geo, _, stats = self.read_columnar(bbox=bbox, refine=refine)
         return (assemble(geo) if geo is not None else []), stats
+
+
+def extra_columns(schema: dict, columns, filter) -> tuple[list, list]:
+    """``(returned, read)`` extra columns of a read: those ``columns``
+    names (every one when None), and those plus the columns ``filter``
+    needs, which decode but are not returned."""
+    want = (list(schema) if columns is None
+            else [c for c in columns if c in schema])
+    if filter is None:
+        return want, list(want)
+    return want, want + [c for c in sorted(filter.columns()) if c not in want]
 
 
 def _bbox_keep_mask(x: np.ndarray, y: np.ndarray, counts: np.ndarray,
@@ -1261,8 +1142,63 @@ def _bbox_keep_mask(x: np.ndarray, y: np.ndarray, counts: np.ndarray,
     return keep
 
 
-def _records_intersecting(cols: GeometryColumns, bbox) -> np.ndarray:
-    """Vectorized exact per-record bbox test (refinement step)."""
-    starts = cols.record_value_starts()
-    counts = np.diff(np.append(starts, cols.n_values))
-    return np.flatnonzero(_bbox_keep_mask(cols.x, cols.y, counts, bbox))
+def _host_keep(x, y, counts, refine_box, attr, edges) -> np.ndarray | None:
+    """The host keep function over records laid out one after another in
+    ``x``/``y`` (``counts`` values each): the bbox test against
+    ``refine_box`` (``refine.host``; None: no refine) ∧ the attribute mask
+    ``attr``, then, with ``edges``, the exact test over the records both
+    keep (``refine.exact``). None when nothing narrows."""
+    keep = None
+    if refine_box is not None:
+        with obs.span("refine.host", cat="refine"):
+            keep = _bbox_keep_mask(x, y, counts, refine_box)
+    if attr is not None:
+        keep = attr if keep is None else keep & attr
+    if edges is not None:
+        with obs.span("refine.exact", cat="refine"):
+            keep = exact_narrow(x, y, counts, *edges, keep, refine_box)
+    return keep
+
+
+def _kept_values(x, y, counts, keep):
+    """The coordinates of the records ``keep`` holds (all when None)."""
+    if keep is None:
+        return x, y
+    counts = np.asarray(counts, np.int64)
+    iv = ragged_ranges((np.cumsum(counts) - counts)[keep], counts[keep])
+    return x[iv], y[iv]
+
+
+def _concat_coords(parts, dtype):
+    if not parts:
+        return np.zeros(0, dtype)
+    if isinstance(parts[0], DeviceCoords):
+        return DeviceCoords.concat(parts)
+    return parts[0] if len(parts) == 1 else np.concatenate(parts)
+
+
+def compact_records(level_parts, keep, x_parts, y_parts, extras, dtype):
+    """A read's result from its parts, cut to the records ``keep`` holds
+    (a mask over the read records; None keeps every one).
+
+    The one compaction rule of every path: the level streams concatenate
+    and keep the slots and sub-geometries of the kept records — a
+    record-aligned subset of canonical levels is canonical, and equals
+    :func:`~repro.core.writer.permute_records` of the kept records; the
+    extra columns take the same mask; ``x_parts``/``y_parts`` already hold
+    only the kept records' coordinates. Returns ``(geo, extras)``, ``geo``
+    None when no level part was read.
+    """
+    if keep is not None:
+        extras = {k: v[keep] for k, v in extras.items()}
+    if level_parts is None or not level_parts[0]:
+        return None, extras
+    types, type_rep, rep, defn = (np.concatenate(p) for p in level_parts)
+    if keep is not None:
+        slot_keep = keep[np.cumsum(rep == 0) - 1]
+        type_keep = keep[np.cumsum(type_rep == 0) - 1]
+        types, type_rep = types[type_keep], type_rep[type_keep]
+        rep, defn = rep[slot_keep], defn[slot_keep]
+    return GeometryColumns(types, type_rep, rep, defn,
+                           _concat_coords(x_parts, dtype),
+                           _concat_coords(y_parts, dtype)), extras

@@ -4,10 +4,11 @@ Handles arbitrary-length inputs (padding with the last element — zero deltas
 are free), Pallas/ref dispatch, and host-side stream compaction to a compact
 byte format (used by checkpoint compression, :mod:`repro.train.checkpoint`).
 
-Also home of the *page-stream* decode entry points (:func:`decode_pages`,
-:func:`build_page_stream`): batched on-device execution of the paper-exact
-FP-delta page format, consumed by ``SpatialParquetReader.read_columnar(
-device="jax")`` — and of the **fused decode→refine** entry point
+Also home of the *page-stream* decode entry points (:func:`build_page_stream`,
+:func:`chunk_plan_pairs`, :func:`decode_stream_device`): batched on-device
+execution of the paper-exact FP-delta page format, consumed by
+``SpatialParquetReader.read_columnar(device="jax")`` — and of the **fused
+decode→refine** entry point
 (:func:`decode_refine_stream`), which chains the page-stream decode with the
 segmented per-record min/max of :mod:`repro.kernels.minmax` and a bbox
 survivor test in one launch chain, so only surviving records (or just the
@@ -32,7 +33,7 @@ import numpy as np
 
 from repro import obs
 from repro.core.columnar import DeviceCoords, ragged_ranges
-from repro.core.fp_delta import HEADER_BITS, FPDeltaPlan, fp_delta_execute
+from repro.core.fp_delta import HEADER_BITS, FPDeltaPlan
 
 from .. import DeviceCompileError, default_interpret
 from . import kernel, ref
@@ -252,7 +253,7 @@ def build_page_stream(plans) -> PageStream:
     value becomes either an *anchor* (page first value, escaped raw value,
     or any raw-mode value — token width W, starts a segment) or an inline
     n-bit delta token. Total payload must stay under ``_MAX_LAUNCH_BITS``
-    (use :func:`decode_pages`, which chunks automatically). The counters
+    (use :func:`chunk_plan_pairs`, which chunks automatically). The counters
     ``launch.values`` and ``launch.values_padded`` add the stream's values
     and the decode lanes it launches (``n_blocks * STREAM_BLOCK``).
     """
@@ -299,8 +300,8 @@ def build_page_stream(plans) -> PageStream:
     if total_bits > _MAX_LAUNCH_BITS:
         raise ValueError(
             f"page stream of {total_bits} bits exceeds the per-launch cap "
-            f"of {_MAX_LAUNCH_BITS}; use decode_pages, which chunks pages "
-            "across launches and host-decodes oversized single pages")
+            f"of {_MAX_LAUNCH_BITS}; use chunk_plan_pairs, which chunks page "
+            "pairs across launches and sends oversized pairs to the host")
 
     words64 = np.concatenate(wparts) if wparts else np.zeros(0, np.uint64)
     # LE uint32 view keeps the bit layout: stream bit b = bit b%32 of word b//32
@@ -400,47 +401,6 @@ def _plan_bits(p: FPDeltaPlan) -> int:
     return (len(p.words) - 1) * 64
 
 
-def decode_pages(plans, *, use_pallas: bool = True,
-                 interpret: bool | None = None) -> list[np.ndarray]:
-    """Decode many host-resolved pages on-device; one array per plan.
-
-    Pages are greedily packed into as few launches under the cap as
-    possible (one launch for a typical row group). A single page too large
-    for any launch falls back to the host ``fp_delta_execute`` — same bits
-    either way — and counts in ``device.host_fallback_pages``. Results are
-    bit-identical to the host decode on every page.
-    """
-    plans = list(plans)
-    out: list[np.ndarray] = []
-
-    def flush(chunk: list[FPDeltaPlan]) -> None:
-        if not chunk:
-            return
-        with obs.span("device.decode_pages", cat="device", pages=len(chunk)):
-            stream = build_page_stream(chunk)
-            vals = decode_page_stream(
-                stream, use_pallas=use_pallas, interpret=interpret)
-        out.extend(np.split(vals, np.cumsum(stream.counts)[:-1]))
-
-    chunk: list[FPDeltaPlan] = []
-    bits = 0
-    for p in plans:
-        pbits = _plan_bits(p)
-        if pbits > _MAX_LAUNCH_BITS:  # one giant page: host-decode it
-            flush(chunk)
-            chunk, bits = [], 0
-            obs.count("device.host_fallback_pages")
-            out.append(fp_delta_execute(p))
-            continue
-        if chunk and bits + pbits > _MAX_LAUNCH_BITS:
-            flush(chunk)
-            chunk, bits = [], 0
-        chunk.append(p)
-        bits += pbits
-    flush(chunk)
-    return out
-
-
 def chunk_plan_pairs(plans, pairs):
     """Group x/y page-pair plans into fused launches under the launch cap.
 
@@ -451,8 +411,8 @@ def chunk_plan_pairs(plans, pairs):
     packed payload alone exceeds the cap (the caller host-decodes it via
     ``fp_delta_execute`` — records never straddle pages, so chunk masks
     concatenate exactly; both pages count in ``device.host_fallback_pages``).
-    Lives next to :data:`_MAX_LAUNCH_BITS` so the cap accounting has a
-    single owner (shared with :func:`decode_pages`).
+    The one launch chunker: it lives next to :data:`_MAX_LAUNCH_BITS`, so
+    the cap accounting has a single owner.
     """
     cur_plans: list = []
     cur_pairs: list = []

@@ -51,7 +51,12 @@ import numpy as np
 
 from repro import obs
 from repro.core.columnar import GeometryColumns
-from repro.core.reader import ReadStats, RowGroupData, _LEVEL_NAMES
+from repro.core.reader import (
+    ReadStats,
+    RowGroupData,
+    compact_records,
+    extra_columns,
+)
 
 __all__ = ["SpatialQuery", "SpatialQueryServer"]
 
@@ -362,8 +367,11 @@ class SpatialQueryServer:
 
     # ------------------------------------------------------------ internals
     def _plan(self, q: SpatialQuery):
-        """Shard/page pruning + metadata-only ReadStats for one query —
-        exactly the accounting of its solo ``scanner.scan``."""
+        """Shard pruning for one query, then each hit shard's own file plan
+        (:meth:`~repro.core.reader.SpatialParquetReader.plan`, the solo
+        scan's): its row groups' page runs and its ``ReadStats``, summed
+        with the pruned shards' totals — the accounting of the query's solo
+        ``scanner.scan``."""
         dindex = self.scanner.index
         hits = [int(i) for i in dindex.query(q.bbox, filter=q.filter)]
         hit_set = set(hits)
@@ -372,41 +380,16 @@ class SpatialQueryServer:
             if i not in hit_set:
                 stats.pages_total += shard.n_pages
                 stats.bytes_total += shard.data_bytes
-        want_extra = (list(self.scanner.extra_schema) if q.columns is None
-                      else [c for c in q.columns
-                            if c in self.scanner.extra_schema])
         # the solo scan also fetches the predicate's columns (then trims
-        # them from the output); mirror that in the byte attribution
-        read_extra = want_extra if q.filter is None else want_extra + sorted(
-            c for c in q.filter.columns() if c not in want_extra)
-        plan: dict[tuple[int, int], list[tuple[int, int]]] = {}
+        # them from the output)
+        want_extra, read_extra = extra_columns(self.scanner.extra_schema,
+                                               q.columns, q.filter)
+        plan = {}
         for shard_i in hits:
-            r = self._reader(shard_i)
-            idx = r.index
-            stats.pages_total += len(idx)
-            stats.bytes_total += r._data_bytes
-            runs_by_rg: dict[int, list[tuple[int, int]]] = {}
-            for rg_i, p0, p1 in idx.page_runs(
-                    q.bbox, hit=idx.query(q.bbox, filter=q.filter)):
-                runs_by_rg.setdefault(rg_i, []).append((p0, p1))
-            for rg_i, runs in runs_by_rg.items():
-                plan[(shard_i, rg_i)] = runs
-                rg = r.footer["row_groups"][rg_i]
-                base = int(np.searchsorted(idx.row_group, rg_i, side="left"))
-                stats.bytes_read += sum(
-                    rg[name]["nbytes"] for name in _LEVEL_NAMES)
-                for p0, p1 in runs:
-                    j0, j1 = base + p0, base + p1 - 1
-                    stats.pages_read += p1 - p0
-                    stats.records_scanned += int(
-                        idx.rec_start[j1] + idx.rec_count[j1]
-                        - idx.rec_start[j0])
-                    stats.bytes_read += int(
-                        idx.x_nbytes[j0 : j1 + 1].sum()
-                        + idx.y_nbytes[j0 : j1 + 1].sum())
-                    for k in read_extra:
-                        stats.bytes_read += sum(
-                            rg["extra"][k][p]["nbytes"] for p in range(p0, p1))
+            _, rgs, shard_stats = self._reader(shard_i).plan(
+                q.bbox, q.filter, read_extra)
+            stats = stats + shard_stats
+            plan.update(((shard_i, rgp.rg_i), rgp) for rgp in rgs)
         return hits, plan, want_extra, stats
 
     def _fill_entry(self, shard_i: int, rg_i: int, qkeys, qvalid):
@@ -496,11 +479,7 @@ class SpatialQueryServer:
             bboxes = [q.bbox for q in wave]
             filters = [q.filter for q in wave]
 
-            acc = [_QueryAccum(list(self.scanner.extra_schema)
-                               if q.columns is None else
-                               [c for c in q.columns
-                                if c in self.scanner.extra_schema])
-                   for q in wave]
+            acc = [_QueryAccum(want_extra) for _, _, want_extra, _ in plans]
             union = sorted({key for _, plan, _, _ in plans for key in plan})
             for shard_i, rg_i in union:
                 touching = [qi for qi, (_, plan, _, _) in enumerate(plans)
@@ -515,17 +494,11 @@ class SpatialQueryServer:
                     self.cache.put(key, entry)
                 keep = self._rg_keep(entry, bboxes, filters, qkeys, qvalid,
                                      wave_keep)
-                idx = self._reader(shard_i).index
-                base = int(np.searchsorted(idx.row_group, rg_i, side="left"))
-                vc = entry.data.rec_vcounts
                 for qi in touching:
-                    runs = plans[qi][1][(shard_i, rg_i)]
+                    rgp = plans[qi][1][(shard_i, rg_i)]
                     a = acc[qi]
                     rec_parts = []
-                    for p0, p1 in runs:
-                        j0, j1 = base + p0, base + p1 - 1
-                        r0 = int(idx.rec_start[j0])
-                        r1 = int(idx.rec_start[j1] + idx.rec_count[j1])
+                    for r0, r1 in rgp.rec_runs:
                         entry.data.levels.append_run(a.level_parts, r0, r1)
                         a.keep_parts.append(keep[qi, r0:r1])
                         for k in a.want_extra:
@@ -550,8 +523,8 @@ class SpatialQueryServer:
 
     def _finalize(self, q: SpatialQuery, hits, want_extra,
                   stats: ReadStats, a: "_QueryAccum") -> None:
-        """Assemble one query's result exactly like the solo fused scan's
-        tail (level compaction by the record-aligned cumsum trick)."""
+        """Assemble one query's result with the reader's own compaction
+        (:func:`~repro.core.reader.compact_records`)."""
         with obs.span("serve.query", cat="serve", qid=q.qid,
                       shards=len(hits)) as sp:
             self._finalize_inner(q, hits, want_extra, stats, a)
@@ -560,42 +533,19 @@ class SpatialQueryServer:
 
     def _finalize_inner(self, q: SpatialQuery, hits, want_extra,
                         stats: ReadStats, a: "_QueryAccum") -> None:
-        do_refine = q.bbox is not None or q.filter is not None
-        keep_all = (np.concatenate(a.keep_parts) if a.keep_parts
+        keep = None
+        if q.bbox is not None or q.filter is not None:
+            keep = (np.concatenate(a.keep_parts) if a.keep_parts
                     else np.zeros(0, bool))
-        types_parts, type_rep_parts, rep_parts, defn_parts = a.level_parts
-        if types_parts:
-            types = np.concatenate(types_parts)
-            type_rep = np.concatenate(type_rep_parts)
-            rep = np.concatenate(rep_parts)
-            defn = np.concatenate(defn_parts)
-            if do_refine:
-                slot_keep = keep_all[np.cumsum(rep == 0) - 1]
-                type_keep = keep_all[np.cumsum(type_rep == 0) - 1]
-                types = types[type_keep]
-                type_rep = type_rep[type_keep]
-                rep = rep[slot_keep]
-                defn = defn[slot_keep]
-            x = (np.concatenate(a.x_parts) if a.x_parts
-                 else np.zeros(0, self.coord_dtype))
-            y = (np.concatenate(a.y_parts) if a.y_parts
-                 else np.zeros(0, self.coord_dtype))
-            q.geo = GeometryColumns(types, type_rep, rep, defn, x, y)
-        else:
-            q.geo = None
-        if hits:
-            extras = {
-                k: (np.concatenate(a.extra_parts[k]) if a.extra_parts[k]
-                    else np.zeros(0, np.dtype(self.scanner.extra_schema[k])))
-                for k in want_extra
-            }
-            if do_refine and q.geo is not None:
-                extras = {k: v[keep_all] for k, v in extras.items()}
-        else:
-            extras = {}
-        q.extras = extras
+        extras = {
+            k: (np.concatenate(a.extra_parts[k]) if a.extra_parts[k]
+                else np.zeros(0, np.dtype(self.scanner.extra_schema[k])))
+            for k in (want_extra if hits else ())
+        }
+        q.geo, q.extras = compact_records(a.level_parts, keep, a.x_parts,
+                                          a.y_parts, extras, self.coord_dtype)
         stats.records_returned = q.geo.n_records if q.geo is not None else (
-            len(next(iter(extras.values()))) if extras else 0)
+            len(next(iter(q.extras.values()))) if q.extras else 0)
         q.stats = stats
         q.done = True
         q.t_done = time.perf_counter()

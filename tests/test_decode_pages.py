@@ -339,11 +339,10 @@ def test_extras_span_per_row_group_with_counters(lake):
     assert pages > len(extras)  # several pages a batch
 
 
-@pytest.mark.parametrize("device", ["cpu", "jax"])
-def test_corrupt_attribute_page_raises_before_decode(rng, tmp_path,
-                                                     monkeypatch, device):
-    """A flipped byte in an attribute page raises a ``ChecksumError`` that
-    names the column and the page, before any attribute page decodes."""
+def _corrupt_points_file(rng, tmp_path, monkeypatch, locate):
+    """A one-row-group point file with two extra columns, a byte of the
+    page ``locate(row_group_footer)`` flipped; every host decode and every
+    device launch is recorded instead of run."""
     n = 6000
     pts = np.round(rng.uniform(-100, 100, (n, 2)), 6)
     cols = from_ragged(np.ones(n, np.uint8), pts, np.ones(n, np.int64),
@@ -354,17 +353,52 @@ def test_corrupt_attribute_page_raises_before_decode(rng, tmp_path,
                       "tag": rng.integers(0, 9, n).astype(np.int32)},
                extra_schema={"ts": "<i8", "tag": "<i4"})
     with SpatialParquetReader(path) as r:
-        page = r.footer["row_groups"][0]["extra"]["tag"][3]
+        page = locate(r.footer["row_groups"][0])
     blob = bytearray(open(path, "rb").read())
     blob[page["offset"] + 2] ^= 0x40
     open(path, "wb").write(bytes(blob))
+    import repro.kernels.fp_delta as fpd
+
     calls = []
-    monkeypatch.setattr(reader_mod, "decode_pages",
-                        lambda *a, **k: calls.append(a))
+    record = lambda *a, **k: calls.append(a)  # noqa: E731
+    monkeypatch.setattr(reader_mod, "decode_pages", record)
+    for name in ("decode_refine_stream", "decode_stream_device"):
+        monkeypatch.setattr(fpd, name, record)
+    return path, page, calls
+
+
+def _read_all(path, device):
     with SpatialParquetReader(path) as r:
         with pytest.raises(ChecksumError) as ei:
             r.read_columnar(bbox=(-100.0, -100.0, 100.0, 100.0), refine=True,
                             device=device)
-    assert ei.value.what == "extra column 'tag' page 3"
-    assert ei.value.offset == page["offset"]
+    return ei.value
+
+
+@pytest.mark.parametrize("device", ["cpu", "jax"])
+def test_corrupt_attribute_page_raises_before_decode(rng, tmp_path,
+                                                     monkeypatch, device):
+    """A flipped byte in an attribute page raises a ``ChecksumError`` that
+    names the column and the page, before any page of its row group
+    decodes (attribute or coordinate) or launches."""
+    path, page, calls = _corrupt_points_file(
+        rng, tmp_path, monkeypatch, lambda rg: rg["extra"]["tag"][3])
+    err = _read_all(path, device)
+    assert err.what == "extra column 'tag' page 3"
+    assert err.offset == page["offset"]
+    assert calls == []
+
+
+@pytest.mark.parametrize("device", ["cpu", "jax"])
+def test_corrupt_coordinate_page_raises_before_decode(rng, tmp_path,
+                                                      monkeypatch, device):
+    """The same for the row group's last y page: every checksum of a row
+    group is verified before any of its pages decodes, on both executors."""
+    path, page, calls = _corrupt_points_file(
+        rng, tmp_path, monkeypatch, lambda rg: rg["y_pages"][-1])
+    with SpatialParquetReader(path) as r:
+        last = len(r.footer["row_groups"][0]["y_pages"]) - 1
+    err = _read_all(path, device)
+    assert err.what == f"y page {last} of row group 0"
+    assert err.offset == page["offset"]
     assert calls == []

@@ -27,12 +27,12 @@ from repro.core.fp_delta import (
     fp_delta_execute,
     fp_delta_plan,
 )
-from repro.core.pages import ENC_FP_DELTA, PageMeta, page_plan
+from repro.core.pages import PageMeta, page_stream_plans
 from repro.kernels.fp_delta import (
     STREAM_BLOCK,
     build_page_stream,
+    chunk_plan_pairs,
     decode_page_stream,
-    decode_pages,
 )
 
 try:
@@ -164,28 +164,42 @@ def test_multi_page_stream_mixed(rng):
     enc = [fp_delta_encode(p, n_bits=nb)[0] for p, nb in zip(pages, n_bits)]
     plans = [fp_delta_plan(e, len(p), dtype) for e, p in zip(enc, pages)]
     host = [fp_delta_decode(e, len(p), dtype) for e, p in zip(enc, pages)]
+    stream = build_page_stream(plans)
     for use_pallas in (False, True):
-        outs = decode_pages(plans, use_pallas=use_pallas, interpret=True)
+        vals = decode_page_stream(stream, use_pallas=use_pallas, interpret=True)
+        outs = np.split(vals, np.cumsum(stream.counts)[:-1])
         assert len(outs) == len(plans)
         for h, o in zip(host, outs):
             assert np.array_equal(_ibits(h), _ibits(o))
 
 
 def test_launch_chunking_and_oversized_page_fallback(rng, monkeypatch):
-    """With a tiny launch cap, decode_pages must split pages across launches
-    and host-decode any single page too large for one — same bits."""
+    """With a tiny launch cap, chunk_plan_pairs must split x/y page pairs
+    across launches and send a pair too large for one to the host — same
+    bits either way."""
     import repro.kernels.fp_delta.ops as fpd_ops
 
-    pages = [_page(rng, n, "sparse", np.float64) for n in (900, 2000, 40, 1500)]
+    sizes = (1200, 600, 2000, 1500, 900, 900)  # x, y pages of three pairs
+    pages = [_page(rng, n, "sparse", np.float64) for n in sizes]
     enc = [fp_delta_encode(p)[0] for p in pages]
     plans = [fp_delta_plan(e, len(p), np.float64) for e, p in zip(enc, pages)]
     # cap below the largest page: forces multi-launch + the host fallback
-    cap = (len(plans[1].words) - 1) * 64 - 1
+    cap = (len(plans[2].words) - 1) * 64 - 1
     monkeypatch.setattr(fpd_ops, "_MAX_LAUNCH_BITS", cap)
     with pytest.raises(ValueError, match="per-launch cap"):
-        build_page_stream([plans[1]])
-    outs = decode_pages(plans, use_pallas=True, interpret=True)
-    for p, o in zip(pages, outs):
+        build_page_stream([plans[2]])
+    pairs = [(0, 1), (1, 2), (2, 3)]  # one record a pair
+    outs, kinds = [], []
+    for kind, cplans, _, _ in chunk_plan_pairs(plans, pairs):
+        kinds.append(kind)
+        if kind == "host":
+            outs += [fp_delta_execute(p) for p in cplans]
+            continue
+        stream = build_page_stream(cplans)
+        vals = decode_page_stream(stream, use_pallas=True, interpret=True)
+        outs += np.split(vals, np.cumsum(stream.counts)[:-1])
+    assert kinds == ["dev", "host", "dev"]
+    for p, o in zip(pages, outs, strict=True):
         assert np.array_equal(_ibits(p), _ibits(o))
 
 
@@ -215,10 +229,17 @@ def test_plan_matches_encoder_stats(rng):
     assert np.array_equal(_ibits(x), _ibits(y))
 
 
-def test_page_plan_requires_fp_delta():
-    meta = PageMeta(0, 8, 1, 0, 1, 0.0, 0.0, "raw", 0, 0)
-    with pytest.raises(ValueError, match="fp_delta"):
-        page_plan(b"\x00" * 8, meta, np.float64, "none")
+def test_page_stream_plans_raw_and_unknown_encodings(rng):
+    """A raw page becomes a synthetic raw-mode plan (n* = 0) that decodes to
+    its stored bits; an unknown encoding is refused."""
+    x = _page(rng, 300, "dense", np.float64)
+    meta = PageMeta(0, x.nbytes, len(x), 0, 1, 0.0, 0.0, "raw", 0, 0)
+    (plan,) = page_stream_plans([(x.tobytes(), meta)], np.float64, "none")
+    assert plan.n == 0 and plan.n_escapes == 0
+    assert np.array_equal(_ibits(x), _ibits(fp_delta_execute(plan)))
+    bad = PageMeta(0, 8, 1, 0, 1, 0.0, 0.0, "dict", 0, 0)
+    with pytest.raises(ValueError, match="unknown encoding"):
+        page_stream_plans([(b"\x00" * 8, bad)], np.float64, "none")
 
 
 # -------------------------------------------------------- reader-level diff
@@ -303,8 +324,9 @@ def _device_roundtrip(x):
     plan = fp_delta_plan(payload, len(x), x.dtype)
     host = fp_delta_decode(payload, len(x), x.dtype)
     assert np.array_equal(_ibits(x), _ibits(host))
+    stream = build_page_stream([plan])
     for use_pallas in (False, True):
-        dev, = decode_pages([plan], use_pallas=use_pallas, interpret=True)
+        dev = decode_page_stream(stream, use_pallas=use_pallas, interpret=True)
         assert np.array_equal(_ibits(x), _ibits(dev))
 
 

@@ -198,12 +198,16 @@ def test_reader_refines_to_zero_after_page_hits(tmp_path):
     ("raw", "none", np.float64),
     ("raw", "gzip", np.float32),
     ("fp_delta", "none", np.float32),
+    ("fp_delta", "gzip", np.int64),
+    ("raw", "none", np.int32),
 ])
 def test_reader_encodings_codecs_widths(tmp_path, enc, codec, dtype):
+    """Integer coordinates decode on the device and refine on the host."""
     cols = DATASETS["eB"](n_points=2500)
-    if np.dtype(dtype) == np.float32:
-        cols = dataclasses.replace(
-            cols, x=cols.x.astype(np.float32), y=cols.y.astype(np.float32))
+    if np.dtype(dtype) != np.float64:
+        scale = 1e5 if np.dtype(dtype).kind == "i" else 1.0
+        cols = dataclasses.replace(cols, x=(cols.x * scale).astype(dtype),
+                                   y=(cols.y * scale).astype(dtype))
     path = tmp_path / f"{enc}_{codec}_{np.dtype(dtype).name}.spqf"
     write_file(path, columns=cols, codec=codec, encoding=enc,
                page_values=700, row_group_records=900)

@@ -24,6 +24,7 @@ from repro.core.geometry import (
     TYPE_POLYGON,
     Geometry,
 )
+from repro.core.filters import Range
 from repro.core.reader import _bbox_keep_mask
 from repro.data.synthetic import ebird_like, porto_taxi_like
 from repro.dataset import SpatialDatasetScanner, write_dataset
@@ -289,6 +290,31 @@ def test_tiny_mb_lake_cpu_jax_checker_agree(mb_lake):
         assert _ids(sc, bbox, "jax", exact=False) == mbr
         dropped += len(mbr) - len(want)
     assert dropped > 0
+
+
+@pytest.mark.parametrize("exact", [False, True])
+@pytest.mark.parametrize("filtered", [False, True])
+def test_host_and_device_stats_agree(mb_lake, filtered, exact):
+    """On a polygon lake with holes (courtyards) both executors return the
+    same records and the same ``ReadStats``, every field, with and without
+    a ``gid`` range and ``exact``."""
+    sc, geoms = mb_lake
+    verts = np.vstack([p for g in geoms for p in g.parts])
+    lo, hi = np.quantile(verts, 0.3, axis=0), np.quantile(verts, 0.7, axis=0)
+    bbox = (lo[0], lo[1], hi[0], hi[1])
+    pred = Range("gid", len(geoms) // 4, 3 * len(geoms) // 4) if filtered else None
+    host, dev = (sc.scan(bbox, refine=True, device=device, filter=pred,
+                         exact=exact) for device in ("cpu", "jax"))
+    assert host[2] == dev[2]
+    assert 0 < host[2].records_returned < host[2].records_scanned
+    assert host[2].pages_read < host[2].pages_total
+    assert (host[0].rep == 2).any()  # a returned polygon has a hole
+    for f in ("types", "type_rep", "rep", "defn"):
+        assert np.array_equal(getattr(host[0], f), getattr(dev[0], f)), f
+    for f in ("x", "y"):
+        assert np.array_equal(getattr(host[0], f).view(np.int64),
+                              getattr(dev[0], f).view(np.int64)), f
+    assert np.array_equal(host[1]["gid"], dev[1]["gid"])
 
 
 @pytest.mark.parametrize("make", [porto_taxi_like, ebird_like],

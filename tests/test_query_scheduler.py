@@ -5,6 +5,7 @@ attribution (see repro/serve/query_scheduler.py)."""
 import numpy as np
 import pytest
 
+from repro.core.filters import Range
 from repro.data.synthetic import PORTO_BBOX, porto_taxi_like
 from repro.dataset.scanner import SpatialDatasetScanner
 from repro.dataset.writer import write_dataset
@@ -65,17 +66,34 @@ def test_concurrent_queries_match_sequential_scan(lake, device):
                 assert np.array_equal(q.extras[k], extras[k]), (device, b, k)
 
 
+def _stat_queries(lake):
+    """``(bbox, columns, filter)``: every box of ``_boxes``, then geometry
+    only, and geometry only under a ``tid`` range (a column the filter
+    reads but the query does not return)."""
+    n = lake.manifest.n_records
+    tid = Range("tid", n // 5, n // 2)
+    return ([(b, None, None) for b in _boxes()]
+            + [(PORTO_BBOX, ("geometry",), None),
+               (PORTO_BBOX, ("geometry",), tid),
+               (_boxes()[4], ("geometry",), tid),
+               (None, None, tid)])
+
+
 @pytest.mark.parametrize("device", ["cpu", "jax"])
 def test_per_query_stats_match_solo_scan(lake, device):
-    boxes = _boxes()
+    queries = _stat_queries(lake)
     with SpatialQueryServer(lake, device=device, cache_rgs=64) as srv:
-        qs = [srv.submit(b) for b in boxes]
+        qs = [srv.submit(b, columns=c, filter=f) for b, c, f in queries]
         srv.run()
-    for q, b in zip(qs, boxes):
-        _, _, st = lake.scan(b, refine=True, device=device, parallel=False)
-        for f in STAT_FIELDS:
-            assert getattr(q.stats, f) == getattr(st, f), (device, b, f)
+    for q, (b, c, f) in zip(qs, queries):
+        geo, extras, st = lake.scan(b, columns=c, refine=True, device=device,
+                                    parallel=False, filter=f)
+        for name in STAT_FIELDS:
+            assert getattr(q.stats, name) == getattr(st, name), (device, b, c, name)
+        _assert_geo_equal(q.geo, geo, (device, b, c, f))
+        assert set(q.extras) == set(extras), (device, b, c, f)
         assert q.latency_s >= 0.0
+    assert 0 < qs[-3].stats.records_returned < qs[-4].stats.records_returned
 
 
 def test_shared_decode_and_cache_hits(lake):
